@@ -26,6 +26,8 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke variant (sets MEMEC_BENCH_FAST=1)")
     args = ap.parse_args()
+    from repro.kernels import dispatch
+    dispatch.enable_compile_cache()
     if args.fast:
         os.environ["MEMEC_BENCH_FAST"] = "1"
     selected = args.only.split(",") if args.only else MODULES

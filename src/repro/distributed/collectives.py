@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import gf256
-from repro.distributed._compat import axis_size
 
 
 @functools.lru_cache(maxsize=None)
@@ -37,7 +36,7 @@ def gf_scale_static(gamma: int, x: jax.Array) -> jax.Array:
 
 def ring_shift(x: jax.Array, axis_name: str, shift: int) -> jax.Array:
     """Send x to (rank + shift) mod A; receive from (rank - shift)."""
-    A = axis_size(axis_name)
+    A = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % A) for i in range(A)]
     return jax.lax.ppermute(x, axis_name, perm)
 
@@ -48,7 +47,7 @@ def ring_xor_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     (A-1) ppermute steps; used on the rare recovery path, where the
     masked-contribution + reduce pattern mirrors the paper's decode-from-k.
     """
-    A = axis_size(axis_name)
+    A = jax.lax.axis_size(axis_name)
     acc = x
     buf = x
 

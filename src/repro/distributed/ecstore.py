@@ -36,7 +36,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.distributed._compat import axis_size, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import gf256
@@ -103,7 +102,7 @@ def parity_delta_update(xor_pages: jax.Array, parity: jax.Array,
     xor_pages: (P, page) local delta; parity: (m, P//k, page) local parity
     buffer.  m*k gamma-scaled ppermutes (shift = (k + r - j) mod A).
     """
-    A = axis_size(cfg.axis)
+    A = jax.lax.axis_size(cfg.axis)
     Pn, page = xor_pages.shape
     S = Pn // cfg.k
     cls = xor_pages.reshape(S, cfg.k, page)
@@ -196,7 +195,7 @@ def reconstruct_failed(pages: jax.Array, parity: jax.Array, failed: jax.Array,
     them so the result lands everywhere (the caller slices/uses it on the
     replacement device).  This is degraded GET at page granularity (§5.4).
     """
-    A = axis_size(cfg.axis)
+    A = jax.lax.axis_size(cfg.axis)
     d = jax.lax.axis_index(cfg.axis)
     Pn, page = pages.shape
     S = Pn // cfg.k
@@ -303,8 +302,8 @@ class ECStateStore:
         return P(self.cfg.axis, *self.extra_axes)
 
     def _wrap(self, fn, in_specs, out_specs):
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def local_pages(self, state) -> jax.Array:
         """(A_data, A_other..., P, page) global view of state pages."""

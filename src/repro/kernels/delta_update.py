@@ -62,7 +62,7 @@ def _scaled_rows(g_ref, x, m: int):
     """rows[r] = gamma_r * x over GF(2^8) via in-kernel xtime powers."""
     rows = []
     for r in range(m):
-        g = g_ref[0, r].astype(jnp.int32)
+        g = g_ref[0, 0, r].astype(jnp.int32)
         acc = jnp.zeros_like(x)
         for b in range(8):
             acc = acc ^ (((x >> b) & 1) * g)
@@ -72,16 +72,19 @@ def _scaled_rows(g_ref, x, m: int):
 
 
 def _delta_apply_batched_kernel(g_ref, p_ref, x_ref, o_ref, *, m: int):
-    x = x_ref[0].astype(jnp.int32)                        # (BC,)
+    x = x_ref[0, 0].astype(jnp.int32)                     # (BC,)
     rows = _scaled_rows(g_ref, x, m)
     o_ref[0] = jnp.stack([p_ref[0, r] ^ rows[r] for r in range(m)])
 
 
 def _delta_only_batched_kernel(g_ref, x_ref, o_ref, *, m: int):
-    x = x_ref[0].astype(jnp.int32)                        # (BC,)
+    x = x_ref[0, 0].astype(jnp.int32)                     # (BC,)
     o_ref[0] = jnp.stack(_scaled_rows(g_ref, x, m))
 
 
+# Layouts: gammas (B, 1, m) and xor (B, 1, C), so every block's last two
+# dims are (1, m) / (1, block_c) over full (1, m) / (1, C) extents — the
+# TPU lowering refuses a (1, m) block over a (B, m) array once B > 1.
 @functools.partial(jax.jit, static_argnames=("m", "block_c", "interpret"))
 def _delta_apply_batched_call(gammas, parity, xor, *, m, block_c, interpret):
     B, _, C = parity.shape
@@ -90,9 +93,9 @@ def _delta_apply_batched_call(gammas, parity, xor, *, m, block_c, interpret):
         functools.partial(_delta_apply_batched_kernel, m=m),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, m), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, m), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, m, block_c), lambda b, c: (b, 0, c)),
-            pl.BlockSpec((1, block_c), lambda b, c: (b, c)),
+            pl.BlockSpec((1, 1, block_c), lambda b, c: (b, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, m, block_c), lambda b, c: (b, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, m, C), jnp.uint8),
@@ -102,14 +105,14 @@ def _delta_apply_batched_call(gammas, parity, xor, *, m, block_c, interpret):
 
 @functools.partial(jax.jit, static_argnames=("m", "block_c", "interpret"))
 def _delta_only_batched_call(gammas, xor, *, m, block_c, interpret):
-    B, C = xor.shape
+    B, _, C = xor.shape
     grid = (B, C // block_c)
     return pl.pallas_call(
         functools.partial(_delta_only_batched_kernel, m=m),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, m), lambda b, c: (b, 0)),
-            pl.BlockSpec((1, block_c), lambda b, c: (b, c)),
+            pl.BlockSpec((1, 1, m), lambda b, c: (b, 0, 0)),
+            pl.BlockSpec((1, 1, block_c), lambda b, c: (b, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, m, block_c), lambda b, c: (b, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, m, C), jnp.uint8),
@@ -146,6 +149,8 @@ def delta_apply_batched(parity: jax.Array | None, gammas: jax.Array,
     Cp = _round_up(C, block_c)
     if Cp != C:
         xor = jnp.pad(xor, ((0, 0), (0, Cp - C)))
+    xor = xor.reshape(B, 1, Cp)
+    gammas = gammas.reshape(B, 1, m)
     if parity is None:
         out = _delta_only_batched_call(gammas, xor, m=m, block_c=block_c,
                                        interpret=interpret)

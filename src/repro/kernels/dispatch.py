@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import pathlib
 
 import jax
 
@@ -35,6 +36,10 @@ XLA = "xla-compiled"         # jitted jnp GF(2^8) formulation (CPU)
 INTERPRET = "interpret"      # pl.pallas_call, interpret=True
 
 _TRUTHY = ("1", "true", "yes", "on")
+
+# fixed compile-cache location when $JAX_COMPILATION_CACHE_DIR is unset
+# (a fixed path: a cache directory that moves between runs never hits)
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +90,23 @@ def decide(interpret: bool | None = None, *, xla_ok: bool = True) -> Decision:
     if backend() in ("tpu", "gpu"):
         return Decision(PALLAS)
     return Decision(XLA) if xla_ok else Decision(INTERPRET)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone; otherwise the cache lives at ``<repo>/.jax_cache``.  The
+    minimum compile time to cache drops to zero, since each coding kernel
+    compiles in well under JAX's one-second default.  Entry points call
+    this once at start-up; importing the package never does.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def describe() -> dict:

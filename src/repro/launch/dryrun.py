@@ -283,7 +283,6 @@ def build_ec_cell(mesh, *, bytes_per_device: int = 1 << 28, op="update"):
     sspec = P(*axes, None, None)
     pspec = P(*axes, None, None, None)
 
-    from repro.distributed._compat import shard_map
     from repro.distributed.ecstore import (parity_delta_update,
                                            parity_delta_update_chain,
                                            reconstruct_failed)
@@ -300,8 +299,8 @@ def build_ec_cell(mesh, *, bytes_per_device: int = 1 << 28, op="update"):
                 par = par.reshape(par.shape[nlead:])
                 out = upd(xp, par, cfg)
                 return out.reshape((1,) * nlead + out.shape)
-            return shard_map(f, mesh=mesh, in_specs=(sspec, pspec),
-                             out_specs=pspec, check_rep=False)(
+            return jax.shard_map(f, mesh=mesh, in_specs=(sspec, pspec),
+                                 out_specs=pspec, check_vma=False)(
                                  xor_pages, parity)
         args = (state_sh, par_sh)
         in_sh = (sspec, pspec)
@@ -313,8 +312,9 @@ def build_ec_cell(mesh, *, bytes_per_device: int = 1 << 28, op="update"):
                 par = par.reshape(par.shape[nlead:])
                 rec = reconstruct_failed(pg, par, jnp.int32(3), cfg)
                 return rec.reshape((1,) * nlead + rec.shape)
-            return shard_map(f, mesh=mesh, in_specs=(sspec, pspec),
-                             out_specs=sspec, check_rep=False)(pages, parity)
+            return jax.shard_map(f, mesh=mesh, in_specs=(sspec, pspec),
+                                 out_specs=sspec,
+                                 check_vma=False)(pages, parity)
         args = (state_sh, par_sh)
         in_sh = (sspec, pspec)
         out_sh = sspec

@@ -6,36 +6,26 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
-
-
-def _mesh(shape, axes):
-    import jax.sharding as jshard
-    if hasattr(jshard, "AxisType"):  # explicit axis types need jax >= 0.6
-        return jax.make_mesh(
-            shape, axes, axis_types=(jshard.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """Version-guarded ``jax.make_mesh``: requests ``AxisType.Auto`` axes
-    where the installed jax has them and plain axes otherwise.  Every
-    mesh construction (tests, examples, launch scripts) must route
-    through here — constructing with ``axis_types=`` directly raises
-    ``AttributeError`` on jax < 0.6."""
-    return _mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh for CPU smoke tests."""
-    return _mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_test_mesh(data: int = 4, model: int = 2):
     """Small mesh for unit tests (needs XLA_FLAGS device count)."""
-    return _mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

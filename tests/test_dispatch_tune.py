@@ -193,6 +193,22 @@ def test_delta_kernels_match_oracle(C, rng):
     assert np.array_equal(got0, want0)
 
 
+@pytest.mark.parametrize("interpret", [True, None])
+def test_delta_only_batched_matches_oracle(interpret, rng):
+    """parity=None (bare deltas) at B > 1, with gammas and xor laid out
+    (B, 1, m) / (B, 1, C) inside the kernel call."""
+    A = np.asarray(RSCode(n=10, k=8).parity_matrix, np.uint8)
+    B, C = 5, 300
+    gammas = A[:, rng.integers(0, A.shape[1], B)].T.astype(np.int32)
+    xors = rng.integers(0, 256, (B, C), dtype=np.uint8)
+    want = np.stack([np.stack([gf256.gf_mul_np(np.full(C, g, np.uint8), x)
+                               for g in gam])
+                     for gam, x in zip(gammas, xors)])
+    got = np.asarray(delta_apply_batched(None, gammas, xors,
+                                         interpret=interpret))
+    assert np.array_equal(got, want)
+
+
 # ---------------------------------------------------------------------------
 # fused engine ops == their two-call compositions
 # ---------------------------------------------------------------------------
@@ -350,3 +366,29 @@ def test_committed_defaults_parse():
     assert raw["entries"], "committed tune defaults are empty"
     for k, v in raw["entries"].items():
         assert "strategy" in v and "block_c" in v, k
+
+
+@pytest.mark.parametrize("scheme", ["rs", "rdp"])
+def test_served_path_never_autotunes(scheme, monkeypatch):
+    """The store only looks tuning entries up: the autotuner, whose
+    ``except`` treats a failing candidate as data, is never reached from
+    SET, UPDATE, recovery or degraded reads."""
+    from repro.core import MemECCluster
+
+    def unreachable(*a, **k):
+        raise AssertionError("autotuner reached from the served path")
+    for name in ("autotune_matmul", "autotune_delta_per_item", "_time_call"):
+        monkeypatch.setattr(tune, name, unreachable)
+    cl = MemECCluster(scheme=scheme, chunk_size=512, max_unsealed=1,
+                      engine="pallas")
+    items = [(b"k%06d" % i, bytes([i % 251]) * (8 + 24 * (i % 2)))
+             for i in range(2000)]
+    for i in range(0, len(items), 64):
+        assert all(cl.multi_set(items[i:i + 64]))
+    ups = [(k, bytes(len(v))) for k, v in items[::3]]
+    assert all(cl.multi_update(ups))
+    cl.fail_server(0)
+    want = dict(items) | dict(ups)
+    keys = list(want)[:300]
+    assert cl.multi_get(keys) == [want[k] for k in keys]
+    assert {"matmul", "delta_per_item"} <= set(cl.engine.op_paths)

@@ -27,7 +27,6 @@ def test_ecstore_encode_delta_reconstruct_vs_oracle():
     p = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         import jax.sharding as jshard
-        from repro.distributed._compat import shard_map
         from repro.distributed.ecstore import (ECConfig, parity_delta_update,
                                                reconstruct_failed, encode_parity)
         from repro.core.codes import RSCode
@@ -40,8 +39,8 @@ def test_ecstore_encode_delta_reconstruct_vs_oracle():
         state = rng.integers(0, 256, (A, 1, Pn, cfg.page_size), dtype=np.uint8)
         sspec = P("data", "model", None, None)
         pspec = P("data", "model", None, None, None)
-        wrap = lambda f, i, o: shard_map(f, mesh=mesh, in_specs=i, out_specs=o,
-                                         check_rep=False)
+        wrap = lambda f, i, o: jax.shard_map(f, mesh=mesh, in_specs=i,
+                                             out_specs=o, check_vma=False)
         def enc(pages):
             def f(pg):
                 out = encode_parity(pg.reshape(pg.shape[2:]), cfg)
@@ -243,8 +242,8 @@ class TestShardingRules:
     def test_fit_spec_demotes_indivisible(self):
         from jax.sharding import PartitionSpec as P
         from repro.distributed.sharding import fit_spec
-        from repro.distributed._compat import abstract_mesh
-        mesh = abstract_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AbstractMesh
+        mesh = AbstractMesh((4, 2), ("data", "model"))
         assert fit_spec(P("data", "model"), (8, 6), mesh) == P("data", "model")
         assert fit_spec(P("data", "model"), (7, 6), mesh) == P(None, "model")
         # unknown axis ("pod") dropped; remaining must divide

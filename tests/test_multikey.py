@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.core import MemECCluster, ServerState
-from repro.core.chunk import ChunkId
+from repro.core.invariants import parity_invariant
 from repro.data.ycsb import YCSBConfig, run_workload
 
 
@@ -15,27 +15,6 @@ def make_cluster(**kw):
                     chunk_size=512, max_unsealed=2, verify_rebuild=True)
     defaults.update(kw)
     return MemECCluster(**defaults)
-
-
-def parity_invariant(cl):
-    bad = checked = 0
-    cs = cl.chunk_size
-    for s in cl.servers:
-        for idx, cid in enumerate(s.chunk_ids):
-            if cid is None or not s.sealed[idx] or cid.position >= cl.k:
-                continue
-            sl = cl.stripe_lists[cid.stripe_list_id]
-            avail = {}
-            for i in range(cl.n):
-                if i == cid.position:
-                    continue
-                c = cl.servers[sl.servers[i]].get_sealed_chunk(
-                    ChunkId(cid.stripe_list_id, cid.stripe_id, i))
-                avail[i] = c if c is not None else np.zeros(cs, np.uint8)
-            rec = cl.code.decode(avail, [cid.position], cs)[cid.position]
-            checked += 1
-            bad += 0 if np.array_equal(rec, s.region[idx]) else 1
-    return checked, bad
 
 
 def batch_load(cl, n, batch=16, seed=0, vsizes=(8, 32)):

@@ -8,7 +8,7 @@ from _hypothesis_compat import given, settings, st  # hypothesis or fallback
 from repro.core import Rebalancer, make_cluster
 from repro.data.ycsb import YCSBConfig, YCSBWorkload, hot_shard_id_map, \
     run_workload
-from test_multikey import parity_invariant
+from repro.core.invariants import parity_invariant
 
 KW = dict(num_servers=10, num_proxies=2, scheme="rs", n=4, k=2, c=8,
           chunk_size=256, max_unsealed=2)

@@ -9,7 +9,7 @@ from repro.core import (MemECCluster, ShardedCluster, engine_specs,
                         make_cluster, resolve_shards, shard_for_key)
 from repro.core.engine import JaxEngine, NumpyEngine
 from repro.data.ycsb import YCSBConfig, YCSBWorkload, run_workload
-from test_multikey import parity_invariant
+from repro.core.invariants import parity_invariant
 
 KW = dict(num_servers=10, num_proxies=2, scheme="rs", n=4, k=2, c=8,
           chunk_size=256, max_unsealed=2)

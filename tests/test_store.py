@@ -5,7 +5,7 @@ import pytest
 from _hypothesis_compat import given, settings, st  # hypothesis or fallback
 
 from repro.core import MemECCluster, PartialFailure, ServerState
-from repro.core.chunk import ChunkId
+from repro.core.invariants import parity_invariant
 
 
 def make_cluster(**kw):
@@ -29,29 +29,6 @@ def load(cl, n, seed=0, vsizes=(8, 32)):
 
 def check_all(cl, kv):
     return sum(1 for k, v in kv.items() if cl.get(k) != v)
-
-
-def parity_invariant(cl):
-    """Every sealed data chunk must decode from the other stripe chunks."""
-    bad = checked = 0
-    cs = cl.chunk_size
-    for s in cl.servers:
-        for idx, cid in enumerate(s.chunk_ids):
-            if cid is None or not s.sealed[idx] or cid.position >= cl.k:
-                continue
-            sl = cl.stripe_lists[cid.stripe_list_id]
-            avail = {}
-            for i in range(cl.n):
-                if i == cid.position:
-                    continue
-                owner = sl.servers[i]
-                c = cl.servers[owner].get_sealed_chunk(
-                    ChunkId(cid.stripe_list_id, cid.stripe_id, i))
-                avail[i] = c if c is not None else np.zeros(cs, np.uint8)
-            rec = cl.code.decode(avail, [cid.position], cs)[cid.position]
-            checked += 1
-            bad += 0 if np.array_equal(rec, s.region[idx]) else 1
-    return checked, bad
 
 
 class TestNormalMode:
