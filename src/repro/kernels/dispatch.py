@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import pathlib
+import re
 
 import jax
 
@@ -37,9 +38,16 @@ INTERPRET = "interpret"      # pl.pallas_call, interpret=True
 
 _TRUTHY = ("1", "true", "yes", "on")
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
 # fixed compile-cache location when $JAX_COMPILATION_CACHE_DIR is unset
 # (a fixed path: a cache directory that moves between runs never hits)
-DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+DEFAULT_CACHE_DIR = REPO_ROOT / ".jax_cache"
+
+# removed from source file names in lowered code: a Pallas kernel's body
+# keeps its source locations, and so its cache key, inside the compiled
+# call, so without this a checkout at another path never hits the cache
+SOURCE_PREFIX_RE = "^" + re.escape(str(REPO_ROOT)) + "/"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,14 +106,18 @@ def enable_compile_cache() -> str:
     ``$JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
     left alone; otherwise the cache lives at ``<repo>/.jax_cache``.  The
     minimum compile time to cache drops to zero, since each coding kernel
-    compiles in well under JAX's one-second default.  Entry points call
-    this once at start-up; importing the package never does.
+    compiles in well under JAX's one-second default, and source file names
+    lose the checkout's own prefix (``SOURCE_PREFIX_RE``), so that every
+    checkout of the same tree shares the entries.  Entry points call this
+    once at start-up; importing the package never does.
     """
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(DEFAULT_CACHE_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      SOURCE_PREFIX_RE)
     return path
 
 

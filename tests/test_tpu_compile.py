@@ -8,7 +8,9 @@ block shapes the TPU lowering refuses.  Shapes are the paper's: RS(10,8)
 and RDP(10,8) at p=17 (16 sub-blocks of 256 bytes), 4 KB chunks,
 batches of 64.
 """
+import base64
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,7 @@ import pytest
 from repro.core import gf256
 from repro.core.codes import RSCode, make_code
 from repro.core.engine import NumpyEngine, block_rep
+from repro.kernels import dispatch
 from repro.kernels.delta_update import (delta_apply_batched,
                                         delta_apply_per_item_batched)
 from repro.kernels.gf256_matmul import gf256_matmul_batched
@@ -122,3 +125,36 @@ def test_delta_apply_batched_compiles(one_chip, with_parity):
         _compile(lambda g, x: delta_apply_batched(None, g, x,
                                                   interpret=False),
                  one_chip, ((B, m), I32), ((B, C), U8))
+
+
+def _kernel_bodies(fn, sharding, *shapes) -> list[bytes]:
+    """The serialized Mosaic bodies of the lowered ``tpu_custom_call``s."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).as_text()
+    found = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+    assert found
+    return [base64.b64decode(b) for b in found]
+
+
+def test_kernel_body_drops_checkout_path(one_chip):
+    """With ``enable_compile_cache``'s source-name rule, a kernel's body
+    (part of its persistent-cache key) names its source relative to the
+    repository, so another checkout of the same tree hits the cache."""
+    root = str(dispatch.REPO_ROOT).encode()
+    shapes = (((B, RS.m, C), U8), ((B, RS.m), I32), ((B, C), U8))
+
+    def lower():
+        return _kernel_bodies(
+            lambda p, g, x: delta_apply_batched(p, g, x, interpret=False),
+            one_chip, *shapes)
+
+    assert any(root in b for b in lower())
+    key = "jax_hlo_source_file_canonicalization_regex"
+    before = getattr(jax.config, key)
+    jax.config.update(key, dispatch.SOURCE_PREFIX_RE)
+    try:
+        bodies = lower()
+    finally:
+        jax.config.update(key, before)
+    assert not any(root in b for b in bodies)
+    assert any(b"src/repro/kernels/delta_update.py" in b for b in bodies)
