@@ -57,8 +57,7 @@ from .shard import (ShardedCluster, ShardedNet, make_cluster, resolve_shards,
 from .store import MemECCluster, PartialFailure
 from .stripe import StripeList, StripeMapper, generate_stripe_lists
 from .trace import (Span, TraceCapture, Tracer, critical_paths,
-                    describe_critical_path, export_chrome, resolve_trace,
-                    validate_chrome)
+                    export_chrome, resolve_trace, validate_chrome)
 from . import telemetry
 from . import trace
 
@@ -76,6 +75,6 @@ __all__ = [
     "shard_for_key", "StripeList", "StripeMapper", "generate_stripe_lists",
     "Placement", "ModPlacement", "RingPlacement", "make_placement",
     "Rebalancer", "MigrationPlan", "telemetry", "trace", "Span", "Tracer",
-    "TraceCapture", "critical_paths", "describe_critical_path",
-    "export_chrome", "resolve_trace", "validate_chrome",
+    "TraceCapture", "critical_paths", "export_chrome", "resolve_trace",
+    "validate_chrome",
 ]

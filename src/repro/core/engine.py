@@ -62,8 +62,9 @@ from collections import OrderedDict
 
 import numpy as np
 
-from . import gf256
+from . import gf256, spans
 from .codes import Code, RDPCode
+from .spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -231,11 +232,11 @@ class CodingEngine:
         # every call merged into a request); the sharded scatter planner
         # sorts shard groups by this clock to drain idle engines first
         self.modeled_busy_s = 0.0
-        # distinct (available-set, wanted) decode patterns submitted per
-        # call, cumulatively — straggler races turn "which Δ dropped"
-        # into per-request erasure sets, so this counter (vs inv_cache
-        # occupancy) shows the pattern diversity they induce
-        self.decode_patterns_submitted = 0
+        # bytes of the host arrays handed to device calls, and of the
+        # results copied back (spans.KERNEL_STAGE / ENGINE_FETCH carry
+        # the same counts per call)
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
         # per-op dispatch provenance: every device hook records which
         # path actually ran it ("pallas-compiled" / "xla-compiled" /
         # "interpret" / "jnp-fallback") — a silent jnp fallback used to
@@ -248,12 +249,12 @@ class CodingEngine:
         if coding_s > 0.0:
             self.modeled_busy_s += coding_s
 
-    def _note_decode_patterns(self, available, wanted):
-        """Count the distinct (sorted available keys, wanted) patterns
-        of one submit_decode call into ``decode_patterns_submitted``."""
-        self.decode_patterns_submitted += len(
-            {(tuple(sorted(a.keys())), tuple(w))
-             for a, w in zip(available, wanted)})
+    def _dispatch(self, op: str, path: str, *operands) -> None:
+        """Note one device call of ``op``: the path it runs on and the
+        host bytes it is handed."""
+        self.device_dispatches += 1
+        self.op_paths[op] = path
+        self.h2d_bytes += spans.host_bytes(*operands)
 
     # -- core batched ops (implemented by backends) ---------------------
     def encode_batch(self, data: np.ndarray) -> np.ndarray:
@@ -302,15 +303,17 @@ class CodingEngine:
         }
 
     def stats(self) -> dict:
-        """Run counters: device dispatches and plan-cache occupancy."""
+        """Run counters: device dispatches, bytes each way and
+        plan-cache occupancy."""
         return {
             "path": self.describe()["path"],
             "op_paths": dict(self.op_paths),
             "device_dispatches": self.device_dispatches,
+            "h2d_bytes": self.h2d_bytes,
+            "d2h_bytes": self.d2h_bytes,
             "inv_cache": len(self._inv_cache),
             "fused_cache": len(self._fused_cache),
             "modeled_busy_s": self.modeled_busy_s,
-            "decode_patterns_submitted": self.decode_patterns_submitted,
         }
 
     # -- modeled work (GF(2^8) multiply-accumulate bytes per batch) -----
@@ -338,7 +341,6 @@ class CodingEngine:
     def submit_decode(self, available, wanted, chunk_size: int) -> EngineFuture:
         available = [dict(a) for a in available]
         wanted = [list(w) for w in wanted]
-        self._note_decode_patterns(available, wanted)
         return EngineFuture(
             lambda: self.decode_batch(available, wanted, chunk_size),
             self.decode_work_bytes(len(available), chunk_size), "decode")
@@ -588,8 +590,7 @@ class JaxEngine(CodingEngine):
         """(O, J) ∘ (B, J, Cb) -> (B, O, Cb) over GF(2^8), device-side."""
         _, jnp = _jax()
         shared, _, _ = _jnp_block_matmuls()
-        self.device_dispatches += 1
-        self.op_paths["matmul"] = "jnp-fallback"
+        self._dispatch("matmul", "jnp-fallback", M, blocks)
         return shared(jnp.asarray(M), jnp.asarray(blocks))
 
     def _matmul_per_item_dev(self, Ms: np.ndarray, blocks: np.ndarray,
@@ -598,8 +599,7 @@ class JaxEngine(CodingEngine):
         ``parity`` (B, O, Cb), when given, is folded in the same jit."""
         _, jnp = _jax()
         _, per_item, per_item_fold = _jnp_block_matmuls()
-        self.device_dispatches += 1
-        self.op_paths["delta_per_item"] = "jnp-fallback"
+        self._dispatch("delta_per_item", "jnp-fallback", Ms, blocks, parity)
         if parity is None:
             return per_item(jnp.asarray(Ms), jnp.asarray(blocks))
         return per_item_fold(jnp.asarray(Ms), jnp.asarray(blocks),
@@ -612,12 +612,16 @@ class JaxEngine(CodingEngine):
                  interpret_forced=dispatch.interpret_forced())
         return d
 
-    @staticmethod
-    def _resolve_dev(dev, shape):
+    def _resolve_dev(self, dev, shape):
         """Blocking resolution of a dispatched device array (the only
         place the async path waits on the device)."""
         jax, _ = _jax()
-        return np.asarray(jax.block_until_ready(dev)).reshape(shape)
+        with span(spans.ENGINE_WAIT):
+            jax.block_until_ready(dev)
+        with span(spans.ENGINE_FETCH, bytes=dev.nbytes):
+            out = np.asarray(dev).reshape(shape)
+        self.d2h_bytes += out.nbytes
+        return out
 
     def submit_encode(self, data):
         data = np.asarray(data, dtype=np.uint8)
@@ -634,14 +638,13 @@ class JaxEngine(CodingEngine):
     def submit_delta(self, data_indices, xors):
         xors = np.asarray(xors, dtype=np.uint8)
         B, C = xors.shape
-        m, k, r = self.code.m, self.code.k, self.rep.r
+        m, r = self.code.m, self.rep.r
         wb = self.delta_work_bytes(B, C)
         if B == 0 or m == 0:
             return EngineFuture.wrap(np.zeros((B, m, C), np.uint8), wb,
                                      "delta")
-        idx = np.asarray(data_indices, dtype=np.int64)
-        cols = self.rep.encode.reshape(m * r, k, r)[:, idx, :]
-        Ms = np.ascontiguousarray(np.transpose(cols, (1, 0, 2)))
+        with span(spans.ENGINE_PREPARE):
+            Ms = self._delta_matrices(data_indices)
         dev = self._matmul_per_item_dev(Ms, xors.reshape(B, r, C // r))
         return EngineFuture(lambda: self._resolve_dev(dev, (B, m, C)),
                             wb, "delta")
@@ -658,11 +661,13 @@ class JaxEngine(CodingEngine):
         wb = B * r * C
         if B == 0 or m == 0:
             return EngineFuture.wrap(parity_rows.copy(), wb, "fold")
-        idx = np.asarray(data_indices, dtype=np.int64)
-        rows = np.asarray(row_indices, dtype=np.int64)
-        # E reshaped (m, r, k, r): item i's system is E4[row_i, :, pos_i, :]
-        E4 = self.rep.encode.reshape(m, r, k, r)
-        Ms = np.ascontiguousarray(E4[rows, :, idx, :])    # (B, r, r)
+        with span(spans.ENGINE_PREPARE):
+            idx = np.asarray(data_indices, dtype=np.int64)
+            rows = np.asarray(row_indices, dtype=np.int64)
+            # E reshaped (m, r, k, r): item i's system is
+            # E4[row_i, :, pos_i, :]
+            E4 = self.rep.encode.reshape(m, r, k, r)
+            Ms = np.ascontiguousarray(E4[rows, :, idx, :])    # (B, r, r)
         dev = self._matmul_per_item_dev(Ms, xors.reshape(B, r, C // r),
                                         parity_rows.reshape(B, r, C // r))
         return EngineFuture(lambda: self._resolve_dev(dev, (B, C)),
@@ -675,13 +680,12 @@ class JaxEngine(CodingEngine):
         parity = np.asarray(parity, dtype=np.uint8)
         xors = np.asarray(xors, dtype=np.uint8)
         B, C = xors.shape
-        m, k, r = self.code.m, self.code.k, self.rep.r
+        m, r = self.code.m, self.rep.r
         wb = self.delta_work_bytes(B, C)
         if B == 0 or m == 0:
             return EngineFuture.wrap(parity.copy(), wb, "apply_delta")
-        idx = np.asarray(data_indices, dtype=np.int64)
-        cols = self.rep.encode.reshape(m * r, k, r)[:, idx, :]
-        Ms = np.ascontiguousarray(np.transpose(cols, (1, 0, 2)))
+        with span(spans.ENGINE_PREPARE):
+            Ms = self._delta_matrices(data_indices)
         dev = self._matmul_per_item_dev(Ms, xors.reshape(B, r, C // r),
                                         parity.reshape(B, m * r, C // r))
         return EngineFuture(lambda: self._resolve_dev(dev, (B, m, C)),
@@ -699,25 +703,35 @@ class JaxEngine(CodingEngine):
         parity = np.asarray(parity, dtype=np.uint8)
         versions = [np.asarray(v, dtype=np.uint8) for v in version_xors]
         B, C = len(versions), parity.shape[2]
-        m, k, r = self.code.m, self.code.k, self.rep.r
+        m, r = self.code.m, self.rep.r
         wb = self.collapse_work_bytes(versions, C)
         if B == 0 or m == 0:
             return EngineFuture.wrap(parity.copy(), wb, "delta_collapse")
         _, jnp = _jax()
-        vmax = max(v.shape[0] for v in versions)
-        stacked = np.zeros((B, vmax, C), dtype=np.uint8)
-        for i, v in enumerate(versions):
-            stacked[i, :v.shape[0]] = v
+        with span(spans.ENGINE_PREPARE):
+            vmax = max(v.shape[0] for v in versions)
+            stacked = np.zeros((B, vmax, C), dtype=np.uint8)
+            for i, v in enumerate(versions):
+                stacked[i, :v.shape[0]] = v
+            Ms = self._delta_matrices(data_indices)
         self.device_dispatches += 1
-        collapsed = _jnp_xor_collapse()(jnp.asarray(stacked))      # (B, C)
-        idx = np.asarray(data_indices, dtype=np.int64)
-        cols = self.rep.encode.reshape(m * r, k, r)[:, idx, :]
-        Ms = np.ascontiguousarray(np.transpose(cols, (1, 0, 2)))
+        self.h2d_bytes += stacked.nbytes
+        with span(spans.KERNEL_STAGE, bytes=stacked.nbytes):
+            stacked = jnp.asarray(stacked)
+        collapsed = _jnp_xor_collapse()(stacked)                   # (B, C)
         dev = self._matmul_per_item_dev(
             Ms, collapsed.reshape(B, r, C // r),
             parity.reshape(B, m * r, C // r))
         return EngineFuture(lambda: self._resolve_dev(dev, (B, m, C)),
                             wb, "delta_collapse")
+
+    def _delta_matrices(self, data_indices) -> np.ndarray:
+        """(B, m*r, r) per item: the encode matrix's columns of its
+        stripe data position."""
+        m, k, r = self.code.m, self.code.k, self.rep.r
+        idx = np.asarray(data_indices, dtype=np.int64)
+        cols = self.rep.encode.reshape(m * r, k, r)[:, idx, :]
+        return np.ascontiguousarray(np.transpose(cols, (1, 0, 2)))
 
     def _blocks(self, chunks: np.ndarray) -> np.ndarray:
         """(B, x, C) -> (B, x*r, C//r) sub-block rows."""
@@ -743,9 +757,9 @@ class JaxEngine(CodingEngine):
         wb = self.decode_work_bytes(len(available), chunk_size)
         if not available:
             return EngineFuture.wrap([], wb, "decode")
-        self._note_decode_patterns(available, wanted)
-        plan = self.plan_decode([a.keys() for a in available], wanted,
-                                chunk_size)
+        with span(spans.ENGINE_PREPARE):
+            plan = self.plan_decode([a.keys() for a in available], wanted,
+                                    chunk_size)
         devs = self._execute_decode_dev(plan, available)
         return EngineFuture(lambda: self._scatter_decode(plan, devs),
                             wb, "decode")
@@ -760,10 +774,11 @@ class JaxEngine(CodingEngine):
         ``tests/test_dispatch_tune.py``."""
         devs = []
         for g in plan.groups:
-            stacked = np.stack(
-                [np.stack([np.asarray(available[i][p], np.uint8)
-                           for p in g.use]) for i in g.idxs])  # (Bg, k, C)
-            M = self._fused_decode_matrix(g)
+            with span(spans.ENGINE_PREPARE):
+                stacked = np.stack(
+                    [np.stack([np.asarray(available[i][p], np.uint8)
+                               for p in g.use]) for i in g.idxs])  # (Bg, k, C)
+                M = self._fused_decode_matrix(g)
             devs.append(self._matmul_dev(M, self._blocks(stacked)))
         return devs
 
@@ -817,15 +832,14 @@ class PallasEngine(JaxEngine):
     def _matmul_dev(self, M, blocks):
         from repro.kernels import dispatch
         from repro.kernels.gf256_matmul import gf256_matmul_batched
-        self.device_dispatches += 1
-        self.op_paths["matmul"] = dispatch.decide().path
+        self._dispatch("matmul", dispatch.decide().path, M, blocks)
         return gf256_matmul_batched(M, blocks)
 
     def _matmul_per_item_dev(self, Ms, blocks, parity=None):
         from repro.kernels import dispatch
         from repro.kernels.delta_update import delta_apply_per_item_batched
-        self.device_dispatches += 1
-        self.op_paths["delta_per_item"] = dispatch.decide().path
+        self._dispatch("delta_per_item", dispatch.decide().path, Ms, blocks,
+                       parity)
         return delta_apply_per_item_batched(parity, Ms, blocks)
 
     def describe(self) -> dict:
@@ -839,21 +853,6 @@ class PallasEngine(JaxEngine):
         return np.ascontiguousarray(
             self.rep.encode[:, idx].T).astype(np.int32)   # (B, m)
 
-    def delta_batch(self, data_indices, xors):
-        if self.rep.r != 1 or self.code.m == 0:
-            return super().delta_batch(data_indices, xors)
-        xors = np.asarray(xors, dtype=np.uint8)
-        B, C = xors.shape
-        if B == 0:
-            return np.zeros((B, self.code.m, C), np.uint8)
-        from repro.kernels import dispatch
-        from repro.kernels.delta_update import delta_apply_batched
-        # parity=None: delta-only kernel — no dead parity streams
-        self.device_dispatches += 1
-        self.op_paths["delta"] = dispatch.decide().path
-        return np.asarray(delta_apply_batched(
-            None, self._gammas(data_indices), xors))
-
     def submit_delta(self, data_indices, xors):
         if self.rep.r != 1 or self.code.m == 0:
             return super().submit_delta(data_indices, xors)
@@ -865,9 +864,11 @@ class PallasEngine(JaxEngine):
                                      wb, "delta")
         from repro.kernels import dispatch
         from repro.kernels.delta_update import delta_apply_batched
-        self.device_dispatches += 1
-        self.op_paths["delta"] = dispatch.decide().path
-        dev = delta_apply_batched(None, self._gammas(data_indices), xors)
+        with span(spans.ENGINE_PREPARE):
+            gammas = self._gammas(data_indices)
+        # parity=None: delta-only kernel — no dead parity streams
+        self._dispatch("delta", dispatch.decide().path, gammas, xors)
+        dev = delta_apply_batched(None, gammas, xors)
         return EngineFuture(
             lambda: self._resolve_dev(dev, (B, self.code.m, C)), wb, "delta")
 
@@ -883,9 +884,10 @@ class PallasEngine(JaxEngine):
             return EngineFuture.wrap(parity.copy(), wb, "apply_delta")
         from repro.kernels import dispatch
         from repro.kernels.delta_update import delta_apply_batched
-        self.device_dispatches += 1
-        self.op_paths["delta"] = dispatch.decide().path
-        dev = delta_apply_batched(parity, self._gammas(data_indices), xors)
+        with span(spans.ENGINE_PREPARE):
+            gammas = self._gammas(data_indices)
+        self._dispatch("delta", dispatch.decide().path, parity, gammas, xors)
+        dev = delta_apply_batched(parity, gammas, xors)
         return EngineFuture(
             lambda: self._resolve_dev(dev, parity.shape), wb, "apply_delta")
 

@@ -58,6 +58,9 @@ import dataclasses
 import os
 from collections import defaultdict
 
+from . import spans
+from .spans import spanned
+
 
 @dataclasses.dataclass
 class Leg:
@@ -506,6 +509,7 @@ class NetSim:
             self.time_by_endpoint[leg.dst] += occupancy
         return self.cost.leg(leg.nbytes, leg.to_failed) * factor
 
+    @spanned(spans.NETSIM)
     def phase(self, legs: list[Leg]) -> float:
         if self.tracer is None:
             worst = 0.0
@@ -517,6 +521,7 @@ class NetSim:
         self.tracer.phase(worst, pairs)
         return worst
 
+    @spanned(spans.NETSIM)
     def race_phase(self, groups: list[tuple[str, list[Leg]]],
                    need: int) -> tuple[float, list[int], list[int]]:
         """k-of-(k+Δ) fan-out: complete at the ``need``-th arrival.
@@ -559,6 +564,7 @@ class NetSim:
                                                       key=lambda e: e[1])])
         return t, winners, dropped
 
+    @spanned(spans.NETSIM)
     def serialized_phase(self, legs: list[Leg]) -> float:
         """Bulk-transfer phase: each destination drains its inbound legs
         sequentially (link-limited), destinations proceed in parallel —
@@ -618,6 +624,7 @@ class NetSim:
         if self.events is not None and coding_s > 0.0:
             self._pending_coding_s += coding_s
 
+    @spanned(spans.NETSIM)
     def record(self, req_kind: str, latency_s: float) -> float:
         """Record one finished request.
 

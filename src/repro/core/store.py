@@ -68,9 +68,11 @@ from .coordinator import Coordinator, ServerState
 from .engine import CodingEngine, make_engine, resolve_async
 from .hotkey import HotTier, resolve_hot_keys
 from .index import fnv1a
+from . import spans
 from .netsim import CostModel, Leg, NetSim
 from .proxy import Proxy
 from .server import Server
+from .spans import span
 from .stripe import StripeList, StripeMapper, generate_stripe_lists
 
 LARGE_MAGIC = b"\x00MEMEC_LRG"
@@ -224,6 +226,13 @@ class MemECCluster:
                       "redundant_reads": 0, "redundant_decodes": 0,
                       "redundant_cancelled": 0,
                       "redundant_replica_fallbacks": 0}
+        # wall seconds of fail_server's four parts (spans.RECOVER_*),
+        # summed over the failures that reached degraded mode, which
+        # ``recoveries`` counts; kept apart from ``stats``, whose counters
+        # are the same in every run of the same requests
+        self.wall_stats = {"recoveries": 0, "recover_transition_s": 0.0,
+                           "recover_gather_s": 0.0, "recover_decode_s": 0.0,
+                           "recover_install_s": 0.0}
 
     @property
     def stats(self) -> dict:
@@ -588,18 +597,19 @@ class MemECCluster:
 
     def multi_get(self, keys, proxy_id: int | None = 0) -> list:
         keys = list(keys)
-        if proxy_id is None and self.num_proxies > 1 and len(keys) > 1:
-            return self._run_proxy_lanes(
-                "MGET", keys,
-                lambda idxs, pid: self._multi_get_impl(
-                    [keys[i] for i in idxs], pid))
-        tr = self._trace_frame()
-        out, t = self._multi_get_impl(keys, proxy_id or 0)
-        if t is not None:
-            self.net.record("MGET", t)
-        elif tr is not None:
-            tr.cancel()
-        return out
+        with span(spans.STORE_MULTI_GET, ops=len(keys)):
+            if proxy_id is None and self.num_proxies > 1 and len(keys) > 1:
+                return self._run_proxy_lanes(
+                    "MGET", keys,
+                    lambda idxs, pid: self._multi_get_impl(
+                        [keys[i] for i in idxs], pid))
+            tr = self._trace_frame()
+            out, t = self._multi_get_impl(keys, proxy_id or 0)
+            if t is not None:
+                self.net.record("MGET", t)
+            elif tr is not None:
+                tr.cancel()
+            return out
 
     def _multi_get_impl(self, keys, proxy_id: int):
         proxy = self.proxies[proxy_id]
@@ -638,18 +648,19 @@ class MemECCluster:
 
     def multi_set(self, items, proxy_id: int | None = 0) -> list[bool]:
         items = list(items)
-        if proxy_id is None and self.num_proxies > 1 and len(items) > 1:
-            return self._run_proxy_lanes(
-                "MSET", [k for k, _ in items],
-                lambda idxs, pid: self._multi_set_impl(
-                    [items[i] for i in idxs], pid))
-        tr = self._trace_frame()
-        ok, t = self._multi_set_impl(items, proxy_id or 0)
-        if t is not None:
-            self.net.record("MSET", t)
-        elif tr is not None:
-            tr.cancel()
-        return ok
+        with span(spans.STORE_MULTI_SET, ops=len(items)):
+            if proxy_id is None and self.num_proxies > 1 and len(items) > 1:
+                return self._run_proxy_lanes(
+                    "MSET", [k for k, _ in items],
+                    lambda idxs, pid: self._multi_set_impl(
+                        [items[i] for i in idxs], pid))
+            tr = self._trace_frame()
+            ok, t = self._multi_set_impl(items, proxy_id or 0)
+            if t is not None:
+                self.net.record("MSET", t)
+            elif tr is not None:
+                tr.cancel()
+            return ok
 
     def _multi_set_impl(self, items, proxy_id: int):
         proxy = self.proxies[proxy_id]
@@ -710,32 +721,33 @@ class MemECCluster:
 
     def multi_update(self, items, proxy_id: int | None = 0) -> list[bool]:
         items = list(items)
-        if self.crash_hook is not None and self.crash_hook[0] == "update":
-            # fault injection must fire exactly as in sequential mode:
-            # everything before the crashing key completes first, the
-            # crash raises, and nothing after it executes
-            hook_i = next((i for i, (k, _) in enumerate(items)
-                           if k == self.crash_hook[1]), None)
-            if hook_i is not None:
-                hook_pid = proxy_id if proxy_id is not None else 0
-                ok = [False] * len(items)
-                ok[:hook_i] = self.multi_update(items[:hook_i], proxy_id)
-                ok[hook_i] = self.update(*items[hook_i], hook_pid)
-                ok[hook_i + 1:] = self.multi_update(items[hook_i + 1:],
-                                                    proxy_id)
-                return ok
-        if proxy_id is None and self.num_proxies > 1 and len(items) > 1:
-            return self._run_proxy_lanes(
-                "MUPDATE", [k for k, _ in items],
-                lambda idxs, pid: self._multi_update_impl(
-                    [items[i] for i in idxs], pid))
-        tr = self._trace_frame()
-        ok, t = self._multi_update_impl(items, proxy_id or 0)
-        if t is not None:
-            self.net.record("MUPDATE", t)
-        elif tr is not None:
-            tr.cancel()
-        return ok
+        with span(spans.STORE_MULTI_UPDATE, ops=len(items)):
+            if self.crash_hook is not None and self.crash_hook[0] == "update":
+                # fault injection must fire exactly as in sequential mode:
+                # everything before the crashing key completes first, the
+                # crash raises, and nothing after it executes
+                hook_i = next((i for i, (k, _) in enumerate(items)
+                               if k == self.crash_hook[1]), None)
+                if hook_i is not None:
+                    hook_pid = proxy_id if proxy_id is not None else 0
+                    ok = [False] * len(items)
+                    ok[:hook_i] = self.multi_update(items[:hook_i], proxy_id)
+                    ok[hook_i] = self.update(*items[hook_i], hook_pid)
+                    ok[hook_i + 1:] = self.multi_update(items[hook_i + 1:],
+                                                        proxy_id)
+                    return ok
+            if proxy_id is None and self.num_proxies > 1 and len(items) > 1:
+                return self._run_proxy_lanes(
+                    "MUPDATE", [k for k, _ in items],
+                    lambda idxs, pid: self._multi_update_impl(
+                        [items[i] for i in idxs], pid))
+            tr = self._trace_frame()
+            ok, t = self._multi_update_impl(items, proxy_id or 0)
+            if t is not None:
+                self.net.record("MUPDATE", t)
+            elif tr is not None:
+                tr.cancel()
+            return ok
 
     def _multi_update_impl(self, items, proxy_id: int):
         proxy = self.proxies[proxy_id]
@@ -805,16 +817,18 @@ class MemECCluster:
                 # parity row of every updated chunk (fused delta+apply —
                 # no separate (B, m, C) delta materialization); the delta
                 # legs are modeled while it is in flight
-                fulls = np.zeros((len(sealed_jobs), self.chunk_size),
-                                 np.uint8)
-                for b, (sl, ds, cid, seg_off, seg, req) in enumerate(sealed_jobs):
-                    fulls[b, seg_off: seg_off + len(seg)] = seg
-                positions = np.array(
-                    [cid.position for _, _, cid, _, _, _ in sealed_jobs])
-                old_par = np.stack(
-                    [np.stack([self._sv(p).parity_row(sl, cid.stripe_id)
-                               for p in sl.parity_servers])
-                     for sl, ds, cid, _, _, _ in sealed_jobs])
+                with span(spans.STORE_PARITY_GATHER):
+                    fulls = np.zeros((len(sealed_jobs), self.chunk_size),
+                                     np.uint8)
+                    for b, (sl, ds, cid, seg_off, seg, req) in enumerate(
+                            sealed_jobs):
+                        fulls[b, seg_off: seg_off + len(seg)] = seg
+                    positions = np.array(
+                        [cid.position for _, _, cid, _, _, _ in sealed_jobs])
+                    old_par = np.stack(
+                        [np.stack([self._sv(p).parity_row(sl, cid.stripe_id)
+                                   for p in sl.parity_servers])
+                         for sl, ds, cid, _, _, _ in sealed_jobs])
                 fut = self.engine.submit_apply_delta(old_par, positions,
                                                      fulls)
                 for sl, ds, cid, seg_off, seg, req in sealed_jobs:
@@ -833,12 +847,13 @@ class MemECCluster:
                 # extraction is stale-proof even when two jobs share a
                 # stripe's parity slot — the delta never depends on the
                 # gathered parity content
-                deltas = fut.result() ^ old_par
-                for (sl, ds, cid, seg_off, seg, req), delta in zip(
-                        sealed_jobs, deltas):
-                    for j, p in enumerate(sl.parity_servers):
-                        self._sv(p).apply_data_delta_row(
-                            sl, cid, delta[j], proxy.pid, req.seq)
+                with span(spans.STORE_DELTA_LOG):
+                    deltas = fut.result() ^ old_par
+                    for (sl, ds, cid, seg_off, seg, req), delta in zip(
+                            sealed_jobs, deltas):
+                        for j, p in enumerate(sl.parity_servers):
+                            self._sv(p).apply_data_delta_row(
+                                sl, cid, delta[j], proxy.pid, req.seq)
             if legs or fut is not None:
                 t += self._merge_coding(self._coding_s(fut), net_t,
                                         kind="delta")
@@ -1483,38 +1498,45 @@ class MemECCluster:
             return 0.0, 0   # no parity — nothing can be reconstructed
         srv = self._sv(sid)
         tasks = []
-        for idx, cid in enumerate(srv.chunk_ids):
-            if cid is None or not srv.sealed[idx]:
-                continue
-            sl = self.stripe_lists[cid.stripe_list_id]
-            r = self.coordinator.redirected_server(sl, sid)
-            if cid.key() in self._rs(r).recon:
-                continue
-            tasks.append((sl, cid, r))
+        avail_list, wanted, all_legs = [], [], []
+        with spans.timed(spans.RECOVER_GATHER, self.wall_stats,
+                         "recover_gather_s"):
+            for idx, cid in enumerate(srv.chunk_ids):
+                if cid is None or not srv.sealed[idx]:
+                    continue
+                sl = self.stripe_lists[cid.stripe_list_id]
+                r = self.coordinator.redirected_server(sl, sid)
+                if cid.key() in self._rs(r).recon:
+                    continue
+                tasks.append((sl, cid, r))
+            for sl, cid, r in tasks:
+                av, legs = self._gather_available(sl, cid.stripe_id,
+                                                  cid.position, r)
+                avail_list.append(av)
+                wanted.append([cid.position])
+                all_legs.extend(legs[: self.k])
         if not tasks:
             return 0.0, 0
-        avail_list, wanted, all_legs = [], [], []
-        for sl, cid, r in tasks:
-            av, legs = self._gather_available(sl, cid.stripe_id,
-                                              cid.position, r)
-            avail_list.append(av)
-            wanted.append([cid.position])
-            all_legs.extend(legs[: self.k])
         # recovery time scales with volume: each redirected server drains
         # its chunk fetches link-serialized, redirected servers in parallel;
         # the one-shot batched decode is submitted first — on jax/pallas
         # the per-pattern matmuls dispatch on-device at submit (plan/
         # execute split) — and its modeled time overlaps the bulk fetches
-        fut = self.engine.submit_decode(avail_list, wanted, self.chunk_size)
-        t = self._merge_coding(self._coding_s(fut),
-                               self.net.serialized_phase(all_legs),
-                               kind="decode")
-        recs = fut.result()
-        for (sl, cid, r), rec in zip(tasks, recs):
-            rc = ReconChunk(cid, np.array(rec[cid.position], np.uint8))
-            if cid.position < self.k:
-                rc.parse()
-            self._rs(r).recon[cid.key()] = rc
+        with spans.timed(spans.RECOVER_DECODE, self.wall_stats,
+                         "recover_decode_s"):
+            fut = self.engine.submit_decode(avail_list, wanted,
+                                            self.chunk_size)
+            t = self._merge_coding(self._coding_s(fut),
+                                   self.net.serialized_phase(all_legs),
+                                   kind="decode")
+            recs = fut.result()
+        with spans.timed(spans.RECOVER_INSTALL, self.wall_stats,
+                         "recover_install_s"):
+            for (sl, cid, r), rec in zip(tasks, recs):
+                rc = ReconChunk(cid, np.array(rec[cid.position], np.uint8))
+                if cid.position < self.k:
+                    rc.parse()
+                self._rs(r).recon[cid.key()] = rc
         self._stats["reconstructions"] += len(tasks)
         self._stats["batch_recovered_chunks"] += len(tasks)
         return t, len(tasks)
@@ -1769,66 +1791,73 @@ class MemECCluster:
         every degraded request reconstructs on demand through
         ``_ensure_recon`` — the paper's §5.4 on-demand mode, used by the
         benchmarks to expose the decode path on degraded GET latency."""
-        if self.hot is not None and len(self.hot.buffer):
-            # failure barrier: collapse every buffered hot-key delta
-            # while the cluster is still healthy — recovery, degraded
-            # decode, and replay all read parity, and buffering stays
-            # paused until the failure set empties (_hot_eligible)
-            self._flush_hot_entries(self.hot.buffer.pop_all(),
-                                    barrier=True)
-        self.failed.add(sid)
-        if not self.degraded_enabled:
-            return {"T_N_to_D": 0.0}
-        t = 0.0
-        # NORMAL -> INTERMEDIATE: atomic broadcast includes the failed
-        # (congested) server — hence the higher latency the paper observes.
-        self.coordinator.set_state(sid, ServerState.INTERMEDIATE)
-        legs = [Leg("state_bcast", 16, "coord", f"s{s}", s in self.failed)
-                for s in range(len(self.servers))]
-        legs += [Leg("state_bcast", 16, "coord", f"p{p.pid}") for p in self.proxies]
-        t += self.net.phase(legs)
-        # resolve inconsistency: revert parity deltas of unacked requests
-        replay: list[tuple[int, object]] = []
-        for proxy in self.proxies:
-            unacked = proxy.unacked_seqs()
-            if not unacked:
-                continue
-            legs = []
-            for srv in self.servers:
-                if srv.sid in self.failed:
+        with spans.timed(spans.RECOVER_TRANSITION, self.wall_stats,
+                         "recover_transition_s"):
+            if self.hot is not None and len(self.hot.buffer):
+                # failure barrier: collapse every buffered hot-key delta
+                # while the cluster is still healthy — recovery, degraded
+                # decode, and replay all read parity, and buffering stays
+                # paused until the failure set empties (_hot_eligible)
+                self._flush_hot_entries(self.hot.buffer.pop_all(),
+                                        barrier=True)
+            self.failed.add(sid)
+            if not self.degraded_enabled:
+                return {"T_N_to_D": 0.0}
+            t = 0.0
+            # NORMAL -> INTERMEDIATE: atomic broadcast includes the failed
+            # (congested) server — hence the higher latency the paper
+            # observes.
+            self.coordinator.set_state(sid, ServerState.INTERMEDIATE)
+            legs = [Leg("state_bcast", 16, "coord", f"s{s}", s in self.failed)
+                    for s in range(len(self.servers))]
+            legs += [Leg("state_bcast", 16, "coord", f"p{p.pid}")
+                     for p in self.proxies]
+            t += self.net.phase(legs)
+            # resolve inconsistency: revert parity deltas of unacked requests
+            replay: list[tuple[int, object]] = []
+            for proxy in self.proxies:
+                unacked = proxy.unacked_seqs()
+                if not unacked:
                     continue
-                nrev = srv.revert_deltas(proxy.pid, unacked)
-                if nrev:
-                    self._stats["reverted_deltas"] += nrev
-                    legs.append(Leg("revert", 16 * nrev, f"p{proxy.pid}",
-                                    f"s{srv.sid}"))
-            if legs:
-                t += self.net.phase(legs)
-            for seq, req in sorted(proxy.pending.items()):
-                if req.data_server == sid or sid in req.stripe_list.servers:
-                    replay.append((proxy.pid, req))
-        # collect key->chunk-ID mapping backups from proxies (§5.3)
-        proxy_maps = []
-        legs = []
-        for proxy in self.proxies:
-            pm = proxy.mappings_for(sid)
-            proxy_maps.append(pm)
-            legs.append(Leg("mapping_push", sum(len(k) + 12 for k, _, _ in pm),
-                            f"p{proxy.pid}", "coord"))
-        t += self.net.phase(legs)
-        self.coordinator.merge_proxy_mappings(sid, proxy_maps)
-        # also merge the server's own mapping log that was checkpointed;
-        # plus anything in its log the proxies still buffer — done above.
-        # INTERMEDIATE -> DEGRADED
-        self.coordinator.set_state(sid, ServerState.DEGRADED)
-        legs = [Leg("state_bcast", 16, "coord", f"s{s}")
-                for s in range(len(self.servers)) if s not in self.failed]
-        legs += [Leg("state_bcast", 16, "coord", f"p{p.pid}") for p in self.proxies]
-        t += self.net.phase(legs)
-        # if sid itself hosted degraded state as a redirect target for an
-        # earlier failure, hand it off to freshly assigned targets
-        t += self._handoff_redirect_state(sid)
-        timings = {"T_N_to_D": t}
+                legs = []
+                for srv in self.servers:
+                    if srv.sid in self.failed:
+                        continue
+                    nrev = srv.revert_deltas(proxy.pid, unacked)
+                    if nrev:
+                        self._stats["reverted_deltas"] += nrev
+                        legs.append(Leg("revert", 16 * nrev, f"p{proxy.pid}",
+                                        f"s{srv.sid}"))
+                if legs:
+                    t += self.net.phase(legs)
+                for seq, req in sorted(proxy.pending.items()):
+                    if (req.data_server == sid
+                            or sid in req.stripe_list.servers):
+                        replay.append((proxy.pid, req))
+            # collect key->chunk-ID mapping backups from proxies (§5.3)
+            proxy_maps = []
+            legs = []
+            for proxy in self.proxies:
+                pm = proxy.mappings_for(sid)
+                proxy_maps.append(pm)
+                legs.append(Leg("mapping_push",
+                                sum(len(k) + 12 for k, _, _ in pm),
+                                f"p{proxy.pid}", "coord"))
+            t += self.net.phase(legs)
+            self.coordinator.merge_proxy_mappings(sid, proxy_maps)
+            # also merge the server's own mapping log that was checkpointed;
+            # plus anything in its log the proxies still buffer — done above.
+            # INTERMEDIATE -> DEGRADED
+            self.coordinator.set_state(sid, ServerState.DEGRADED)
+            legs = [Leg("state_bcast", 16, "coord", f"s{s}")
+                    for s in range(len(self.servers)) if s not in self.failed]
+            legs += [Leg("state_bcast", 16, "coord", f"p{p.pid}")
+                     for p in self.proxies]
+            t += self.net.phase(legs)
+            # if sid itself hosted degraded state as a redirect target for an
+            # earlier failure, hand it off to freshly assigned targets
+            t += self._handoff_redirect_state(sid)
+            timings = {"T_N_to_D": t}
         # fast batched recovery (§5.4): reconstruct every chunk the failed
         # server owned in one batched decode at the redirected servers,
         # so degraded requests (and the replay below) hit a warm cache.
@@ -1838,21 +1867,24 @@ class MemECCluster:
                         else (0.0, 0))
         timings["T_recovery"] = t_rec
         timings["recovered_chunks"] = n_rec
+        self.wall_stats["recoveries"] += 1
         # replay incomplete requests as degraded requests
-        for pid, req in replay:
-            self.proxies[pid].pending.pop(req.seq, None)
-            self.proxies[pid].ack(req.seq)
-            if req.kind == "SET":
-                self._degraded_set(self.proxies[pid], req.stripe_list,
-                                   req.data_server, req.key, req.value)
-            elif req.kind == "UPDATE":
-                self._degraded_mutate("update", self.proxies[pid],
-                                      req.stripe_list, req.data_server,
-                                      req.key, req.value)
-            elif req.kind == "DELETE":
-                self._degraded_mutate("delete", self.proxies[pid],
-                                      req.stripe_list, req.data_server,
-                                      req.key, None)
+        with spans.timed(spans.RECOVER_TRANSITION, self.wall_stats,
+                         "recover_transition_s"):
+            for pid, req in replay:
+                self.proxies[pid].pending.pop(req.seq, None)
+                self.proxies[pid].ack(req.seq)
+                if req.kind == "SET":
+                    self._degraded_set(self.proxies[pid], req.stripe_list,
+                                       req.data_server, req.key, req.value)
+                elif req.kind == "UPDATE":
+                    self._degraded_mutate("update", self.proxies[pid],
+                                          req.stripe_list, req.data_server,
+                                          req.key, req.value)
+                elif req.kind == "DELETE":
+                    self._degraded_mutate("delete", self.proxies[pid],
+                                          req.stripe_list, req.data_server,
+                                          req.key, None)
         return timings
 
     def _handoff_redirect_state(self, failing: int) -> float:

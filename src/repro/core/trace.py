@@ -516,16 +516,6 @@ def critical_paths(cluster) -> dict:
     return out
 
 
-def describe_critical_path(entry: dict, top: int = 3) -> str:
-    """Human one-liner: ``"61% get:p0->s5, 24% engine:decode, ..."``."""
-    lat = entry["latency_s"]
-    if not lat:
-        return "0s"
-    parts = [f"{100.0 * v / lat:.0f}% {k}"
-             for k, v in list(entry["components"].items())[:top]]
-    return ", ".join(parts)
-
-
 # -- Chrome trace-event export ---------------------------------------------
 
 def _tid_label(span: Span) -> str:

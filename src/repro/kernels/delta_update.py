@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import spans
+from repro.core.spans import span
 from repro.kernels import dispatch
 
 DEFAULT_BLOCK_C = 2048
@@ -139,28 +141,33 @@ def delta_apply_batched(parity: jax.Array | None, gammas: jax.Array,
         from repro.kernels import xla_gf256
         return xla_gf256.delta_batched(gammas, xor, parity)
     interpret = dec.interpret
-    xor = jnp.asarray(xor, dtype=jnp.uint8)
-    gammas = jnp.asarray(gammas, dtype=jnp.int32)
-    B, m = gammas.shape
-    C = xor.shape[1]
-    if B == 0 or m == 0:
-        return jnp.zeros((B, m, C), jnp.uint8)
-    block_c = min(block_c, _round_up(C, 128))
-    Cp = _round_up(C, block_c)
-    if Cp != C:
-        xor = jnp.pad(xor, ((0, 0), (0, Cp - C)))
-    xor = xor.reshape(B, 1, Cp)
-    gammas = gammas.reshape(B, 1, m)
-    if parity is None:
-        out = _delta_only_batched_call(gammas, xor, m=m, block_c=block_c,
-                                       interpret=interpret)
+    with span(spans.KERNEL_STAGE,
+              bytes=spans.host_bytes(parity, gammas, xor)):
+        xor = jnp.asarray(xor, dtype=jnp.uint8)
+        gammas = jnp.asarray(gammas, dtype=jnp.int32)
+        B, m = gammas.shape
+        C = xor.shape[1]
+        if B == 0 or m == 0:
+            return jnp.zeros((B, m, C), jnp.uint8)
+        block_c = min(block_c, _round_up(C, 128))
+        Cp = _round_up(C, block_c)
+        if Cp != C:
+            xor = jnp.pad(xor, ((0, 0), (0, Cp - C)))
+        xor = xor.reshape(B, 1, Cp)
+        gammas = gammas.reshape(B, 1, m)
+        if parity is not None:
+            parity = jnp.asarray(parity, dtype=jnp.uint8)
+            if Cp != C:
+                parity = jnp.pad(parity, ((0, 0), (0, 0), (0, Cp - C)))
+    with span(spans.KERNEL_CALL):
+        if parity is None:
+            out = _delta_only_batched_call(gammas, xor, m=m, block_c=block_c,
+                                           interpret=interpret)
+        else:
+            out = _delta_apply_batched_call(gammas, parity, xor, m=m,
+                                            block_c=block_c,
+                                            interpret=interpret)
         return out[:, :, :C]
-    parity = jnp.asarray(parity, dtype=jnp.uint8)
-    if Cp != C:
-        parity = jnp.pad(parity, ((0, 0), (0, 0), (0, Cp - C)))
-    out = _delta_apply_batched_call(gammas, parity, xor, m=m,
-                                    block_c=block_c, interpret=interpret)
-    return out[:, :, :C]
 
 
 def delta_apply_per_item_batched(parity: jax.Array | None, Ms, blocks, *,

@@ -40,7 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core import gf256
+from repro.core import gf256, spans
+from repro.core.spans import span
 from repro.kernels import dispatch
 
 DEFAULT_BLOCK_C = 2048
@@ -200,9 +201,8 @@ def gf256_matmul_batched(A: np.ndarray, data: jax.Array, *,
     from repro.kernels import tune, xla_gf256
     A = np.asarray(A, dtype=np.uint8)
     m, k = A.shape
-    data = jnp.asarray(data, dtype=jnp.uint8)
-    B, kd, C = data.shape
-    assert kd == k, (data.shape, k)
+    B, kd, C = np.shape(data)
+    assert kd == k, (np.shape(data), k)
     if B == 0 or m == 0:
         return jnp.zeros((B, m, C), jnp.uint8)
     dec = dispatch.decide(interpret)
@@ -219,26 +219,23 @@ def gf256_matmul_batched(A: np.ndarray, data: jax.Array, *,
         return xla_gf256.matmul_batched(A, data, strategy=s)
     block_c = min(block_c or DEFAULT_BLOCK_C, _round_up(C, 128))
     Cp = _round_up(C, block_c)
-    if Cp != C:
-        data = jnp.pad(data, ((0, 0), (0, 0), (0, Cp - C)))
     if strategy not in PALLAS_STRATEGIES:
         strategy = ("unroll" if m * k * 8 <= MAX_UNROLL_OPS
                     else "gf01" if cls == "01" else "cols")
     if strategy == "gf01" and cls != "01":
         strategy = "cols"
-    if strategy == "unroll":
-        apow = jnp.asarray(build_apow(A))
-        out = _gf_matmul_batched_call(apow, data, m=m, k=k, block_c=block_c,
-                                      interpret=dec.interpret)
-    elif strategy == "gf01":
-        out = _gf01_matmul_call(jnp.asarray(A.astype(np.int32)), data,
-                                m=m, k=k, block_c=block_c,
-                                interpret=dec.interpret)
-    else:
-        apow = jnp.asarray(build_apow(A))
-        out = _gf_matmul_cols_call(apow, data, m=m, k=k, block_c=block_c,
-                                   interpret=dec.interpret)
-    return out[:, :, :C]
+    with span(spans.KERNEL_STAGE, bytes=spans.host_bytes(A, data)):
+        data = jnp.asarray(data, dtype=jnp.uint8)
+        if Cp != C:
+            data = jnp.pad(data, ((0, 0), (0, 0), (0, Cp - C)))
+        mat = jnp.asarray(A.astype(np.int32) if strategy == "gf01"
+                          else build_apow(A))
+    with span(spans.KERNEL_CALL):
+        call = {"unroll": _gf_matmul_batched_call, "gf01": _gf01_matmul_call,
+                "cols": _gf_matmul_cols_call}[strategy]
+        out = call(mat, data, m=m, k=k, block_c=block_c,
+                   interpret=dec.interpret)
+        return out[:, :, :C]
 
 
 def gf256_matmul(A: np.ndarray, data: jax.Array, *,
@@ -357,10 +354,9 @@ def gf256_matmul_per_item_batched(Ms, blocks, parity=None, *,
     """
     from repro.kernels import xla_gf256
     Ms = np.asarray(Ms, dtype=np.uint8)
-    blocks = jnp.asarray(blocks, dtype=jnp.uint8)
     B, O, J = Ms.shape
-    assert blocks.shape[:2] == (B, J), (Ms.shape, blocks.shape)
-    C = blocks.shape[2]
+    *lead, C = np.shape(blocks)
+    assert tuple(lead) == (B, J), (Ms.shape, np.shape(blocks))
     if B == 0 or O == 0:
         return (jnp.asarray(parity, jnp.uint8) if parity is not None
                 else jnp.zeros((B, O, C), jnp.uint8))
@@ -371,20 +367,25 @@ def gf256_matmul_per_item_batched(Ms, blocks, parity=None, *,
     is01 = int(Ms.max(initial=0)) <= 1 and strategy != "cols"
     block_c = min(block_c or DEFAULT_BLOCK_C, _round_up(C, 128))
     Cp = _round_up(C, block_c)
-    if Cp != C:
-        blocks = jnp.pad(blocks, ((0, 0), (0, 0), (0, Cp - C)))
-    Ms_dev = jnp.asarray(Ms.astype(np.int32))
-    if parity is None:
-        out = _per_item_call(Ms_dev, blocks, o=O, j=J, block_c=block_c,
-                             interpret=dec.interpret, is01=is01)
-    else:
-        parity = jnp.asarray(parity, dtype=jnp.uint8)
+    with span(spans.KERNEL_STAGE,
+              bytes=spans.host_bytes(Ms, blocks, parity)):
+        blocks = jnp.asarray(blocks, dtype=jnp.uint8)
         if Cp != C:
-            parity = jnp.pad(parity, ((0, 0), (0, 0), (0, Cp - C)))
-        out = _per_item_fold_call(Ms_dev, parity, blocks, o=O, j=J,
-                                  block_c=block_c, interpret=dec.interpret,
-                                  is01=is01)
-    return out[:, :, :C]
+            blocks = jnp.pad(blocks, ((0, 0), (0, 0), (0, Cp - C)))
+        Ms_dev = jnp.asarray(Ms.astype(np.int32))
+        if parity is not None:
+            parity = jnp.asarray(parity, dtype=jnp.uint8)
+            if Cp != C:
+                parity = jnp.pad(parity, ((0, 0), (0, 0), (0, Cp - C)))
+    with span(spans.KERNEL_CALL):
+        if parity is None:
+            out = _per_item_call(Ms_dev, blocks, o=O, j=J, block_c=block_c,
+                                 interpret=dec.interpret, is01=is01)
+        else:
+            out = _per_item_fold_call(Ms_dev, parity, blocks, o=O, j=J,
+                                      block_c=block_c,
+                                      interpret=dec.interpret, is01=is01)
+        return out[:, :, :C]
 
 
 def _round_up(x: int, mult: int) -> int:

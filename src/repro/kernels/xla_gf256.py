@@ -31,7 +31,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import gf256
+from repro.core import gf256, spans
+from repro.core.spans import span
 
 _LANES = np.uint32(0x01010101)  # bit b of each packed byte after >> b
 
@@ -153,24 +154,24 @@ def matmul_batched(A: np.ndarray, data, *, strategy: str | None = None):
     """XLA twin of ``gf256_matmul_batched``: (B, k, C) -> (B, m, C)."""
     A = np.asarray(A, dtype=np.uint8)
     m, k = A.shape
-    data = _as_u8(data)
-    B, kd, C = data.shape
-    assert kd == k, (data.shape, k)
+    B, kd, C = np.shape(data)
+    assert kd == k, (np.shape(data), k)
     if B == 0 or m == 0:
         return jnp.zeros((B, m, C), jnp.uint8)
     if strategy is None or (strategy == SELECT32 and int(A.max()) > 1):
         strategy = default_strategy(A)
-    if strategy == TABLE:
-        return _matmul_table(_mat_dev("u8", A.shape, A.tobytes()), data,
-                             m=m, k=k)
-    data, C = _pad4(data)
-    if strategy == SELECT32:
-        out = _matmul_select32(_mat_dev("u32", A.shape, A.tobytes()), data,
-                               m=m, k=k)
-    else:
-        out = _matmul_bitplane32(_mat_dev("apow", A.shape, A.tobytes()),
-                                 data, m=m, k=k)
-    return out if out.shape[-1] == C else out[:, :, :C]
+    kind = {TABLE: "u8", SELECT32: "u32"}.get(strategy, "apow")
+    with span(spans.KERNEL_STAGE, bytes=spans.host_bytes(A, data)):
+        data = _as_u8(data)
+        if strategy != TABLE:
+            data, C = _pad4(data)
+        mat = _mat_dev(kind, A.shape, A.tobytes())
+    with span(spans.KERNEL_CALL):
+        if strategy == TABLE:
+            return _matmul_table(mat, data, m=m, k=k)
+        fn = _matmul_select32 if strategy == SELECT32 else _matmul_bitplane32
+        out = fn(mat, data, m=m, k=k)
+        return out if out.shape[-1] == C else out[:, :, :C]
 
 
 # ---------------------------------------------------------------------------
@@ -297,26 +298,31 @@ def matmul_per_item(Ms, blocks, parity=None, *, strategy: str | None = None):
     trip for the fold)."""
     Ms = np.asarray(Ms, dtype=np.uint8) if isinstance(Ms, np.ndarray) \
         else jnp.asarray(Ms, dtype=jnp.uint8)
-    blocks = _as_u8(blocks)
     B, O, J = Ms.shape
-    C = blocks.shape[2]
+    C = np.shape(blocks)[2]
     if B == 0 or O == 0:
         return jnp.zeros((B, O, C), jnp.uint8)
     if strategy is None or (strategy == SELECT32
                             and int(np.asarray(Ms).max()) > 1):
         strategy = default_strategy(np.asarray(Ms))
     fold = parity is not None
-    par = (_as_u8(parity) if fold
-           else jnp.zeros((), jnp.uint8))
-    if strategy == TABLE:
-        return _per_item_table(jnp.asarray(Ms), blocks, par,
-                               o=O, j=J, fold=fold)
-    blocks, C = _pad4(blocks)
-    if fold:
-        par, _ = _pad4(par)
-    fn = _per_item_select32 if strategy == SELECT32 else _per_item_bitplane32
-    out = fn(jnp.asarray(Ms), blocks, par, o=O, j=J, fold=fold)
-    return out[:, :, :C]
+    with span(spans.KERNEL_STAGE,
+              bytes=spans.host_bytes(Ms, blocks, parity)):
+        blocks = _as_u8(blocks)
+        par = (_as_u8(parity) if fold
+               else jnp.zeros((), jnp.uint8))
+        if strategy != TABLE:
+            blocks, C = _pad4(blocks)
+            if fold:
+                par, _ = _pad4(par)
+        Ms = jnp.asarray(Ms)
+    with span(spans.KERNEL_CALL):
+        if strategy == TABLE:
+            return _per_item_table(Ms, blocks, par, o=O, j=J, fold=fold)
+        fn = (_per_item_select32 if strategy == SELECT32
+              else _per_item_bitplane32)
+        out = fn(Ms, blocks, par, o=O, j=J, fold=fold)
+        return out[:, :, :C]
 
 
 # ---------------------------------------------------------------------------
@@ -367,16 +373,18 @@ def delta_single(parity, gammas, old, new):
 def delta_batched(gammas, xors, parity=None):
     """XLA twin of ``delta_apply_batched``: per-item gamma rows, 8 packed
     bit-plane steps; ``parity`` folds in-jit when given."""
-    xors = _as_u8(xors)
-    gammas = jnp.asarray(gammas, dtype=jnp.uint32)
-    B, m = gammas.shape
-    C = xors.shape[1]
+    B, m = np.shape(gammas)
+    C = np.shape(xors)[1]
     if B == 0 or m == 0:
         return jnp.zeros((B, m, C), jnp.uint8)
     fold = parity is not None
-    xors, C = _pad4(xors)
-    par = jnp.zeros((), jnp.uint8)
-    if fold:
-        par, _ = _pad4(_as_u8(parity))
-    out = _delta_bitplane32(gammas, xors, par, m=m, fold=fold)
-    return out[:, :, :C]
+    with span(spans.KERNEL_STAGE,
+              bytes=spans.host_bytes(parity, gammas, xors)):
+        gammas = jnp.asarray(gammas, dtype=jnp.uint32)
+        xors, C = _pad4(_as_u8(xors))
+        par = jnp.zeros((), jnp.uint8)
+        if fold:
+            par, _ = _pad4(_as_u8(parity))
+    with span(spans.KERNEL_CALL):
+        out = _delta_bitplane32(gammas, xors, par, m=m, fold=fold)
+        return out[:, :, :C]
