@@ -84,42 +84,72 @@ def _delta_only_batched_kernel(g_ref, x_ref, o_ref, *, m: int):
     o_ref[0] = jnp.stack(_scaled_rows(g_ref, x, m))
 
 
-# Layouts: gammas (B, 1, m) and xor (B, 1, C), so every block's last two
-# dims are (1, m) / (1, block_c) over full (1, m) / (1, C) extents — the
-# TPU lowering refuses a (1, m) block over a (B, m) array once B > 1.
-@functools.partial(jax.jit, static_argnames=("m", "block_c", "interpret"))
-def _delta_apply_batched_call(gammas, parity, xor, *, m, block_c, interpret):
-    B, _, C = parity.shape
-    grid = (B, C // block_c)
-    return pl.pallas_call(
+def _batched_layout(gammas, xor, block_c: int):
+    """Inside the jit: int32 gammas laid out (B, 1, m) and uint8 xor
+    padded to whole ``block_c`` tiles, laid out (B, 1, Cp) — every
+    block's last two dims are then (1, m) / (1, block_c) over full
+    (1, m) / (1, Cp) extents, since the TPU lowering refuses a (1, m)
+    block over a (B, m) array once B > 1.  Returns them with the
+    effective ``block_c`` and ``Cp``."""
+    B, m = gammas.shape
+    C = xor.shape[1]
+    block_c = min(block_c, _round_up(C, 128))
+    Cp = _round_up(C, block_c)
+    xor = _pad_last(xor.astype(jnp.uint8), Cp)
+    return (gammas.astype(jnp.int32).reshape(B, 1, m),
+            xor.reshape(B, 1, Cp), block_c, Cp)
+
+
+def _pad_last(x, width: int):
+    """``x`` zero-padded along its last axis to ``width``."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+# Each front-door call is ONE jitted program: the casts, pads and
+# layouts above, the kernel and the trailing slice all run inside it, so
+# host numpy operands reach the device through the jit's own argument
+# transfer with no eager device op before it.
+@functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
+def _delta_apply_batched_call(parity, gammas, xor, *, block_c, interpret):
+    B, m = gammas.shape
+    C = xor.shape[1]
+    gammas, xor, block_c, Cp = _batched_layout(gammas, xor, block_c)
+    parity = _pad_last(parity.astype(jnp.uint8), Cp)
+    out = pl.pallas_call(
         functools.partial(_delta_apply_batched_kernel, m=m),
-        grid=grid,
+        grid=(B, Cp // block_c),
         in_specs=[
             pl.BlockSpec((1, 1, m), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, m, block_c), lambda b, c: (b, 0, c)),
             pl.BlockSpec((1, 1, block_c), lambda b, c: (b, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, m, block_c), lambda b, c: (b, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((B, m, C), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((B, m, Cp), jnp.uint8),
         interpret=interpret,
     )(gammas, parity, xor)
+    return out[:, :, :C]
 
 
-@functools.partial(jax.jit, static_argnames=("m", "block_c", "interpret"))
-def _delta_only_batched_call(gammas, xor, *, m, block_c, interpret):
-    B, _, C = xor.shape
-    grid = (B, C // block_c)
-    return pl.pallas_call(
+@functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
+def _delta_only_batched_call(gammas, xor, *, block_c, interpret):
+    B, m = gammas.shape
+    C = xor.shape[1]
+    gammas, xor, block_c, Cp = _batched_layout(gammas, xor, block_c)
+    out = pl.pallas_call(
         functools.partial(_delta_only_batched_kernel, m=m),
-        grid=grid,
+        grid=(B, Cp // block_c),
         in_specs=[
             pl.BlockSpec((1, 1, m), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, 1, block_c), lambda b, c: (b, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, m, block_c), lambda b, c: (b, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((B, m, C), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((B, m, Cp), jnp.uint8),
         interpret=interpret,
     )(gammas, xor)
+    return out[:, :, :C]
 
 
 def delta_apply_batched(parity: jax.Array | None, gammas: jax.Array,
@@ -135,39 +165,28 @@ def delta_apply_batched(parity: jax.Array | None, gammas: jax.Array,
     ``parity=None`` returns the bare deltas gamma_r·xor — same kernel
     minus the parity read/write streams, for callers that fold the delta
     into host-side buffers themselves.
+
+    On the Pallas path the operands go unchanged to one jitted program
+    (host numpy arrays, device arrays or tracers alike), which casts,
+    pads and lays them out itself.
     """
     dec = dispatch.decide(interpret)
     if dec.path == dispatch.XLA:
         from repro.kernels import xla_gf256
         return xla_gf256.delta_batched(gammas, xor, parity)
-    interpret = dec.interpret
     with span(spans.KERNEL_STAGE,
               bytes=spans.host_bytes(parity, gammas, xor)):
-        xor = jnp.asarray(xor, dtype=jnp.uint8)
-        gammas = jnp.asarray(gammas, dtype=jnp.int32)
         B, m = gammas.shape
         C = xor.shape[1]
         if B == 0 or m == 0:
             return jnp.zeros((B, m, C), jnp.uint8)
-        block_c = min(block_c, _round_up(C, 128))
-        Cp = _round_up(C, block_c)
-        if Cp != C:
-            xor = jnp.pad(xor, ((0, 0), (0, Cp - C)))
-        xor = xor.reshape(B, 1, Cp)
-        gammas = gammas.reshape(B, 1, m)
-        if parity is not None:
-            parity = jnp.asarray(parity, dtype=jnp.uint8)
-            if Cp != C:
-                parity = jnp.pad(parity, ((0, 0), (0, 0), (0, Cp - C)))
     with span(spans.KERNEL_CALL):
         if parity is None:
-            out = _delta_only_batched_call(gammas, xor, m=m, block_c=block_c,
-                                           interpret=interpret)
-        else:
-            out = _delta_apply_batched_call(gammas, parity, xor, m=m,
-                                            block_c=block_c,
-                                            interpret=interpret)
-        return out[:, :, :C]
+            return _delta_only_batched_call(gammas, xor, block_c=block_c,
+                                            interpret=dec.interpret)
+        return _delta_apply_batched_call(parity, gammas, xor,
+                                         block_c=block_c,
+                                         interpret=dec.interpret)
 
 
 def delta_apply_per_item_batched(parity: jax.Array | None, Ms, blocks, *,
