@@ -23,11 +23,14 @@ import numpy as np
 from jax.profiler import TraceAnnotation as span
 
 # proxy and store (core/store.py): the public multi-key entries (arg
-# ``ops``), the parity gather before a batched UPDATE's engine call, and
-# the parity-delta log after it
+# ``ops``), the block of coordinated (degraded) requests a ``multi_get``
+# or ``multi_update`` runs before its batch (arg ``ops``), the parity
+# gather before a batched UPDATE's engine call, and the parity-delta log
+# after it
 STORE_MULTI_GET = "memec.store.multi_get"
 STORE_MULTI_UPDATE = "memec.store.multi_update"
 STORE_MULTI_SET = "memec.store.multi_set"
+STORE_DEGRADED = "memec.store.degraded"
 STORE_PARITY_GATHER = "memec.store.parity_gather"
 STORE_DELTA_LOG = "memec.store.delta_log"
 

@@ -219,6 +219,7 @@ class MemECCluster:
                       "reverted_deltas": 0, "degraded_requests": 0,
                       "migrated_objects": 0, "migrated_chunks": 0,
                       "batch_recovered_chunks": 0, "redirect_handoffs": 0,
+                      "two_loss_rebuilds": 0,
                       "modeled_coding_s": 0.0, "intra_overlap_saved_s": 0.0,
                       "proxy_lane_batches": 0, "proxy_lane_saved_s": 0.0,
                       "engine_queue_wait_s": 0.0,
@@ -279,6 +280,16 @@ class MemECCluster:
         return self.degraded_enabled and self.coordinator.state_of(sid) in (
             ServerState.INTERMEDIATE, ServerState.DEGRADED,
             ServerState.COORDINATED_NORMAL)
+
+    def _coordinated(self, *sids: int) -> bool:
+        """True if a request involving these servers is degraded: one of
+        them is down with degraded mode active."""
+        return any(self._is_failed(s) and self._degraded_active(s)
+                   for s in sids)
+
+    def _stripe_losses(self, sl: StripeList) -> int:
+        """Chunks a stripe of ``sl`` has lost: its servers that are down."""
+        return sum(self._is_failed(s) for s in sl.servers)
 
     def _positions(self, sl: StripeList) -> list[int]:
         return list(sl.servers)
@@ -397,7 +408,7 @@ class MemECCluster:
         per_parity: dict[int, list[tuple]] = {}
         for sl, ds, ev in items:
             for p in sl.parity_servers:
-                if self._is_failed(p) and self._degraded_active(p):
+                if self._coordinated(p):
                     t += self._seal_to_failed_parity(sl, ds, ev, p)
                     continue
                 legs.append(Leg("seal", ev.payload_bytes, f"s{ds}", f"s{p}",
@@ -468,7 +479,7 @@ class MemECCluster:
         failed data server through the redirect state: shadowed objects,
         the batched-decode reconstruction cache, then a parity replica."""
         sl, ds = self.mapper.data_server_for(key)
-        if not (self._is_failed(ds) and self._degraded_active(ds)):
+        if not self._coordinated(ds):
             return self._sv(ds).get_value(key)
         r = self.coordinator.redirected_server(sl, ds)
         rs = self._rs(r)
@@ -499,7 +510,7 @@ class MemECCluster:
         # path; a failed data server resolves through the degraded view.
         sl, ds = self.mapper.data_server_for(key)
         head = None
-        if self._is_failed(ds) and self._degraded_active(ds):
+        if self._coordinated(ds):
             head = self.peek_value(key)
         else:
             srv = self._sv(ds)
@@ -595,6 +606,15 @@ class MemECCluster:
             tr.cancel()
         return results
 
+    def _degraded_block(self, idxs: list[int], request) -> list:
+        """``request(i)`` for each of a window's degraded requests, in
+        request order and duplicates included, as one block under one
+        span.  Each takes the coordinated single-key path (§5.4)."""
+        if not idxs:
+            return []
+        with span(spans.STORE_DEGRADED, ops=len(idxs)):
+            return [request(i) for i in idxs]
+
     def multi_get(self, keys, proxy_id: int | None = 0) -> list:
         keys = list(keys)
         with span(spans.STORE_MULTI_GET, ops=len(keys)):
@@ -614,13 +634,16 @@ class MemECCluster:
     def _multi_get_impl(self, keys, proxy_id: int):
         proxy = self.proxies[proxy_id]
         out: list = [None] * len(keys)
-        plan = []
+        plan, degraded = [], []
         for i, key in enumerate(keys):
             sl, ds = self.mapper.data_server_for(key)
-            if self._is_failed(ds) and self._degraded_active(ds):
-                out[i] = self.get(key, proxy_id)       # degraded fallback
+            if self._coordinated(ds):
+                degraded.append(i)
             else:
                 plan.append((i, key, sl, ds))
+        for i, v in zip(degraded, self._degraded_block(
+                degraded, lambda i: self.get(keys[i], proxy_id))):
+            out[i] = v
         t = None
         if plan:
             if self.redundant_reads > 0 and self.code.m > 0:
@@ -668,12 +691,10 @@ class MemECCluster:
         batch, deferred, seen = [], [], set()
         for i, (key, value) in enumerate(items):
             sl, ds = self.mapper.data_server_for(key)
-            involved = [ds] + list(sl.parity_servers)
             if key in seen:
                 deferred.append((i, key, value))       # keep batch order
             elif (object_size(len(key), len(value)) > self.chunk_size
-                  or any(self._degraded_active(s) and self._is_failed(s)
-                         for s in involved)
+                  or self._coordinated(ds, *sl.parity_servers)
                   or self._sv(ds).lookup(key) is not None):
                 ok[i] = self.set(key, value, proxy_id)  # fallback
             else:
@@ -752,23 +773,29 @@ class MemECCluster:
     def _multi_update_impl(self, items, proxy_id: int):
         proxy = self.proxies[proxy_id]
         ok = [False] * len(items)
-        batch, deferred, seen = [], [], set()
+        batch, deferred, seen, degraded, large = [], [], set(), [], []
         for i, (key, value) in enumerate(items):
             sl, ds = self.mapper.data_server_for(key)
-            involved = [ds] + list(sl.parity_servers)
             if key in seen:
                 deferred.append((i, key, value))
                 continue
-            if any(self._degraded_active(s) and self._is_failed(s)
-                   for s in involved):
-                ok[i] = self.update(key, value, proxy_id)  # degraded
+            if self._coordinated(ds, *sl.parity_servers):
+                degraded.append(i)      # duplicates too: never in ``seen``
                 continue
             head = self._sv(ds).get_value(key)
             if head is not None and head.startswith(LARGE_MAGIC):
-                ok[i] = self._update_large(key, value, proxy_id)
+                large.append(i)
                 continue
             seen.add(key)
             batch.append((i, key, value, sl, ds, head))
+        # the degraded keys and the large objects are disjoint sets of
+        # keys, so running the one group before the other changes no
+        # answer, acknowledgement or parity byte
+        for i, done in zip(degraded, self._degraded_block(
+                degraded, lambda i: self.update(*items[i], proxy_id))):
+            ok[i] = done
+        for i in large:
+            ok[i] = self._update_large(*items[i], proxy_id)
         t = None
         if batch:
             # head-probe round trip (sequential update() pays a modeled
@@ -876,8 +903,7 @@ class MemECCluster:
     def _set_small(self, key: bytes, value: bytes, proxy_id: int):
         proxy = self.proxies[proxy_id]
         sl, ds = self.mapper.data_server_for(key)
-        involved = [ds] + list(sl.parity_servers)
-        if any(self._degraded_active(s) and self._is_failed(s) for s in involved):
+        if self._coordinated(ds, *sl.parity_servers):
             return self._degraded_set(proxy, sl, ds, key, value)
         req = proxy.begin("SET", key, value, sl, ds)
         t = 0.0
@@ -1007,7 +1033,7 @@ class MemECCluster:
                 # unsealed: replicated at every alive parity server
                 cands = sorted(
                     (self._endpoint_load(p), p) for p in sl.parity_servers
-                    if not (self._is_failed(p) and self._degraded_active(p)))
+                    if not self._coordinated(p))
                 cands = cands[:delta]
                 groups = [primary]
                 for _, p in cands:
@@ -1039,8 +1065,7 @@ class MemECCluster:
             cand_pos = sorted(
                 (self._endpoint_load(owner), i >= self.k, i)
                 for i, owner in enumerate(sl.servers)
-                if i != pos and not (self._is_failed(owner)
-                                     and self._degraded_active(owner)))
+                if i != pos and not self._coordinated(owner))
             take = cand_pos[: self.k - 1 + delta]
             groups, members = [primary], [pos]
             for _, _, i in take:
@@ -1097,7 +1122,7 @@ class MemECCluster:
     def _get_small(self, key: bytes, proxy_id: int):
         proxy = self.proxies[proxy_id]
         sl, ds = self.mapper.data_server_for(key)
-        if self._is_failed(ds) and self._degraded_active(ds):
+        if self._coordinated(ds):
             return self._degraded_get(proxy, sl, ds, key)
         if self.redundant_reads > 0 and self.code.m > 0:
             # straggler-tolerant k-of-(k+Δ) read (contents byte-identical
@@ -1259,8 +1284,7 @@ class MemECCluster:
                       proxy_id: int) -> bool:
         proxy = self.proxies[proxy_id]
         sl, ds = self.mapper.data_server_for(key)
-        involved = [ds] + list(sl.parity_servers)
-        if any(self._degraded_active(s) and self._is_failed(s) for s in involved):
+        if self._coordinated(ds, *sl.parity_servers):
             return self._degraded_mutate(kind, proxy, sl, ds, key, value)
         self._trace_frame()
         req = proxy.begin(kind.upper(), key, value, sl, ds)
@@ -1421,7 +1445,7 @@ class MemECCluster:
         degraded-mode updates the memory never saw."""
         owner = sl.data_servers[i]
         cid = self._stripe_chunk_id(sl, stripe_id, i)
-        if self._is_failed(owner) and self._degraded_active(owner):
+        if self._coordinated(owner):
             r = self.coordinator.redirected_server(sl, owner)
             rc = self._rs(r).recon.get(cid.key())
             if rc is not None:
@@ -1486,6 +1510,7 @@ class MemECCluster:
             rc.parse()
         rs.recon[cid.key()] = rc
         self._stats["reconstructions"] += 1
+        self._stats["two_loss_rebuilds"] += self._stripe_losses(sl) > 1
         return rc, t
 
     def _batch_recover_server(self, sid: int) -> tuple[float, int]:
@@ -1539,6 +1564,8 @@ class MemECCluster:
                 self._rs(r).recon[cid.key()] = rc
         self._stats["reconstructions"] += len(tasks)
         self._stats["batch_recovered_chunks"] += len(tasks)
+        self._stats["two_loss_rebuilds"] += sum(
+            self._stripe_losses(sl) > 1 for sl, _, _ in tasks)
         return t, len(tasks)
 
     def _degraded_get(self, proxy: Proxy, sl: StripeList, ds: int, key: bytes):
@@ -1786,6 +1813,14 @@ class MemECCluster:
 
     def fail_server(self, sid: int, recover: bool = True) -> dict:
         """Inject a transient failure; returns transition timings.
+
+        A second failure while the first is still down is the double
+        failure RS(10,8) and RDP(10,8) tolerate (n - k = 2): stripes
+        holding a chunk of both servers have lost two, and each chunk of
+        ``sid`` rebuilds from the eight survivors of its stripe, never
+        from the first server's rebuilt copies (``two_loss_rebuilds``
+        counts them); if ``sid`` hosts the first server's redirected
+        state, that state is handed off first.
 
         ``recover=False`` skips the eager one-shot batched recovery so
         every degraded request reconstructs on demand through

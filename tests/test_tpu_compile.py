@@ -112,6 +112,31 @@ def test_delta_apply_per_item_batched_compiles(one_chip, name, Ms, width):
              one_chip, ((B, O, width), U8), ((B, J, width), U8))
 
 
+@pytest.mark.parametrize("lost, wanted", [
+    ((2, 3), (3,)),        # two data chunks lost, one rebuilt: 128 x 128
+    ((3, 8), (8,)),        # data and row parity lost, parity: 144 x 128
+])
+@pytest.mark.parametrize("batch", [1, 5])
+def test_rdp_two_loss_decode_compiles(one_chip, lost, wanted, batch):
+    """Recovery with two servers down: each chunk decodes from the eight
+    survivors of its stripe, one small group per loss pattern."""
+    avail = [p for p in range(RDP.n) if p not in lost]
+    (g,) = NumpyEngine(RDP).plan_decode([avail], [wanted], C).groups
+    A = np.asarray(g.inv if g.par_rows is None else np.concatenate(
+        [g.inv, gf256.gf_matmul_np(g.par_rows, g.inv)]), np.uint8)
+    _compile(lambda d: gf256_matmul_batched(A, d, interpret=False),
+             one_chip, ((batch, A.shape[1], C // R), U8))
+
+
+def test_rdp_single_key_delta_compiles(one_chip):
+    """A degraded RDP UPDATE's parity delta: one item, no parity operand
+    (the delta-only program)."""
+    Ms = np.ascontiguousarray(E4[None, :, 3, :])          # (1, m*r, r)
+    _compile(lambda x: delta_apply_per_item_batched(None, Ms, x,
+                                                    interpret=False),
+             one_chip, ((1, R, C // R), U8))
+
+
 @pytest.mark.parametrize("with_parity", [True, False])
 def test_delta_apply_batched_compiles(one_chip, with_parity):
     """The RS batched UPDATE kernels at B=64 (refused by the TPU lowering
