@@ -45,9 +45,10 @@ ENGINE_FETCH = "memec.engine.fetch"
 
 # kernel dispatch (kernels/delta_update.py, kernels/gf256_matmul.py,
 # kernels/xla_gf256.py): the work before the jitted call (host-side only
-# in delta_apply_batched; the operands' copies to the device and their
-# pads in the other front doors), then the jitted call, with the
-# argument transfers it makes itself
+# in delta_apply_batched and gf256_matmul_per_item_batched; the
+# operands' copies to the device and their pads in the other front
+# doors), then the jitted call, with the argument transfers it makes
+# itself
 KERNEL_STAGE = "memec.kernel.stage"
 KERNEL_CALL = "memec.kernel.call"
 

@@ -23,6 +23,8 @@ from jax.experimental import pallas as pl
 from repro.core import spans
 from repro.core.spans import span
 from repro.kernels import dispatch
+from repro.kernels.gf256_matmul import (_pad_last, _round_up,
+                                        gf256_matmul_per_item_batched)
 
 DEFAULT_BLOCK_C = 2048
 
@@ -98,14 +100,6 @@ def _batched_layout(gammas, xor, block_c: int):
     xor = _pad_last(xor.astype(jnp.uint8), Cp)
     return (gammas.astype(jnp.int32).reshape(B, 1, m),
             xor.reshape(B, 1, Cp), block_c, Cp)
-
-
-def _pad_last(x, width: int):
-    """``x`` zero-padded along its last axis to ``width``."""
-    pad = width - x.shape[-1]
-    if pad == 0:
-        return x
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 # Each front-door call is ONE jitted program: the casts, pads and
@@ -206,7 +200,6 @@ def delta_apply_per_item_batched(parity: jax.Array | None, Ms, blocks, *,
     entries steer strategy × block_c when the caller doesn't.
     """
     from repro.kernels import tune
-    from repro.kernels.gf256_matmul import gf256_matmul_per_item_batched
     import numpy as np
     Ms = np.asarray(Ms, dtype=np.uint8)
     B, O, J = Ms.shape
@@ -247,6 +240,3 @@ def delta_update(parity: jax.Array, gammas: jax.Array, old: jax.Array,
                       interpret=interpret)
     return out[:, :C]
 
-
-def _round_up(x: int, mult: int) -> int:
-    return -(-x // mult) * mult

@@ -302,41 +302,57 @@ def _per_item_fold_kernel(m_ref, p_ref, d_ref, o_ref, *, o: int, j: int,
     o_ref[0] = p_ref[0] ^ _per_item_acc(m_ref, d, o, j, is01).astype(jnp.uint8)
 
 
+def _pad_last(x, width: int):
+    """``x`` zero-padded along its last axis to ``width``."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+# Each per-item front-door call is ONE jitted program: the casts (``Ms``
+# crosses as uint8 and widens to int32 on the device), the pads to whole
+# ``block_c`` tiles, the kernel and the trailing slice all run inside it,
+# so host numpy operands reach the device through the jit's own argument
+# transfer with no eager device op before it.
 @functools.partial(jax.jit,
                    static_argnames=("o", "j", "block_c", "interpret", "is01"))
 def _per_item_call(Ms, data, *, o, j, block_c, interpret, is01):
     B, _, C = data.shape
-    grid = (B, C // block_c)
-    return pl.pallas_call(
+    Cp = _round_up(C, block_c)
+    out = pl.pallas_call(
         functools.partial(_per_item_kernel, o=o, j=j, is01=is01),
-        grid=grid,
+        grid=(B, Cp // block_c),
         in_specs=[
             pl.BlockSpec((1, o, j), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, j, block_c), lambda b, c: (b, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, o, block_c), lambda b, c: (b, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((B, o, C), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((B, o, Cp), jnp.uint8),
         interpret=interpret,
-    )(Ms, data)
+    )(Ms.astype(jnp.int32), _pad_last(data.astype(jnp.uint8), Cp))
+    return out[:, :, :C]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("o", "j", "block_c", "interpret", "is01"))
 def _per_item_fold_call(Ms, parity, data, *, o, j, block_c, interpret, is01):
     B, _, C = data.shape
-    grid = (B, C // block_c)
-    return pl.pallas_call(
+    Cp = _round_up(C, block_c)
+    out = pl.pallas_call(
         functools.partial(_per_item_fold_kernel, o=o, j=j, is01=is01),
-        grid=grid,
+        grid=(B, Cp // block_c),
         in_specs=[
             pl.BlockSpec((1, o, j), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, o, block_c), lambda b, c: (b, 0, c)),
             pl.BlockSpec((1, j, block_c), lambda b, c: (b, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, o, block_c), lambda b, c: (b, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((B, o, C), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((B, o, Cp), jnp.uint8),
         interpret=interpret,
-    )(Ms, parity, data)
+    )(Ms.astype(jnp.int32), _pad_last(parity.astype(jnp.uint8), Cp),
+      _pad_last(data.astype(jnp.uint8), Cp))
+    return out[:, :, :C]
 
 
 def gf256_matmul_per_item_batched(Ms, blocks, parity=None, *,
@@ -351,6 +367,11 @@ def gf256_matmul_per_item_batched(Ms, blocks, parity=None, *,
     XORed into the product inside the same kernel (one read stream more,
     one device round trip fewer).  Grid = (batch, C-tiles), like
     ``gf256_matmul_batched``; 0/1 matrices drop the bit-plane loop.
+
+    On the Pallas path the operands go unchanged to one jitted program
+    (host numpy arrays, device arrays or tracers alike), which casts and
+    pads them itself; only shape checks, the 0/1 test of ``Ms`` and the
+    tile width run on the host before it.
     """
     from repro.kernels import xla_gf256
     Ms = np.asarray(Ms, dtype=np.uint8)
@@ -364,28 +385,17 @@ def gf256_matmul_per_item_batched(Ms, blocks, parity=None, *,
     if dec.path == dispatch.XLA:
         s = strategy if strategy in xla_gf256.STRATEGIES else None
         return xla_gf256.matmul_per_item(Ms, blocks, parity, strategy=s)
-    is01 = int(Ms.max(initial=0)) <= 1 and strategy != "cols"
-    block_c = min(block_c or DEFAULT_BLOCK_C, _round_up(C, 128))
-    Cp = _round_up(C, block_c)
     with span(spans.KERNEL_STAGE,
               bytes=spans.host_bytes(Ms, blocks, parity)):
-        blocks = jnp.asarray(blocks, dtype=jnp.uint8)
-        if Cp != C:
-            blocks = jnp.pad(blocks, ((0, 0), (0, 0), (0, Cp - C)))
-        Ms_dev = jnp.asarray(Ms.astype(np.int32))
-        if parity is not None:
-            parity = jnp.asarray(parity, dtype=jnp.uint8)
-            if Cp != C:
-                parity = jnp.pad(parity, ((0, 0), (0, 0), (0, Cp - C)))
+        is01 = int(Ms.max(initial=0)) <= 1 and strategy != "cols"
+        block_c = min(block_c or DEFAULT_BLOCK_C, _round_up(C, 128))
     with span(spans.KERNEL_CALL):
         if parity is None:
-            out = _per_item_call(Ms_dev, blocks, o=O, j=J, block_c=block_c,
-                                 interpret=dec.interpret, is01=is01)
-        else:
-            out = _per_item_fold_call(Ms_dev, parity, blocks, o=O, j=J,
-                                      block_c=block_c,
-                                      interpret=dec.interpret, is01=is01)
-        return out[:, :, :C]
+            return _per_item_call(Ms, blocks, o=O, j=J, block_c=block_c,
+                                  interpret=dec.interpret, is01=is01)
+        return _per_item_fold_call(Ms, parity, blocks, o=O, j=J,
+                                   block_c=block_c, interpret=dec.interpret,
+                                   is01=is01)
 
 
 def _round_up(x: int, mult: int) -> int:

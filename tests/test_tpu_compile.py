@@ -112,6 +112,21 @@ def test_delta_apply_per_item_batched_compiles(one_chip, name, Ms, width):
              one_chip, ((B, O, width), U8), ((B, J, width), U8))
 
 
+@pytest.mark.parametrize("with_parity", [True, False])
+def test_rdp_delta_cols_compiles(one_chip, with_parity):
+    """RDP's update shape forced onto the bit-plane body (``is01`` false),
+    as a ``strategy="cols"`` tuning entry steers it."""
+    Ms = np.ascontiguousarray(np.broadcast_to(E4[:, 0, :], (B, RDP.m * R, R)))
+    x = ((B, R, C // R), U8)
+    if with_parity:
+        _compile(lambda p, d: delta_apply_per_item_batched(
+            p, Ms, d, strategy="cols", interpret=False),
+            one_chip, ((B, RDP.m * R, C // R), U8), x)
+    else:
+        _compile(lambda d: delta_apply_per_item_batched(
+            None, Ms, d, strategy="cols", interpret=False), one_chip, x)
+
+
 @pytest.mark.parametrize("lost, wanted", [
     ((2, 3), (3,)),        # two data chunks lost, one rebuilt: 128 x 128
     ((3, 8), (8,)),        # data and row parity lost, parity: 144 x 128
