@@ -25,8 +25,8 @@ from jax.profiler import TraceAnnotation as span
 # proxy and store (core/store.py): the public multi-key entries (arg
 # ``ops``), the block of coordinated (degraded) requests a ``multi_get``
 # or ``multi_update`` runs before its batch (arg ``ops``), the parity
-# gather before a batched UPDATE's engine call, and the parity-delta log
-# after it
+# gather before the engine call of a sealed UPDATE/DELETE round (batched
+# or a single key's batch of one), and the parity-delta log after it
 STORE_MULTI_GET = "memec.store.multi_get"
 STORE_MULTI_UPDATE = "memec.store.multi_update"
 STORE_MULTI_SET = "memec.store.multi_set"

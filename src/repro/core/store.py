@@ -23,35 +23,22 @@ Implementation deviations from the paper (each noted inline):
   degraded mode routes through the mutate path (upsert); shadow replicas
   migrate to *every* restored parity server of a list.
 
-Intra-shard async pipeline (PR 4): coding now carries a modeled cost
-(``CostModel.coding_s`` over ``CodingEngine`` work bytes).  With
-``async_engine=False`` (default, ``$MEMEC_ASYNC``) coding time adds
-serially to a request's network phases; with ``async_engine=True`` the
-store *submits* engine work (``engine.submit_*`` futures) while the same
-shard's netsim legs are modeled in flight and charges
-``max(coding, network)`` per phase — plus two further overlaps: the seal
-fan-out runs concurrently with the SET acks, and ``multi_*`` requests
-with ``proxy_id=None`` spread across the shard's proxies as concurrent
-lanes (``NetSim.merge_lanes``; per-server serialization preserved).
-Stored bytes are identical in both modes — only the synchronization
-points and the latency accounting move.  ``stats["intra_overlap_saved_s"]``
-tracks the genuine sync-vs-async win (phases the sync pipeline pays as a
-sum); ``stats["proxy_lane_saved_s"]`` tracks lane overlap relative to
-serially executed per-proxy calls (a different baseline — sync callers
-issuing one batch per proxy call never pay that serialization).
+Each normal-mode operation has one body, ``_get_batch``, ``_set_batch``
+and ``_mutate_batch``: the ``multi_*`` API runs a batch through it and a
+single-key request is a batch of one.
 
-Plan/execute decode + engine queue (PR 5): ``submit_decode`` now
-dispatches on-device at submit on the jax/pallas backends (the engine
-builds a ``DecodePlan`` from host metadata), so degraded reconstruction
-(``_ensure_recon``) and ``fail_server`` batched recovery genuinely
-overlap decode with their fetch legs; their share of the async win is
-``stats["decode_overlap_saved_s"]``.  The degraded-mutate redirect
-deltas are likewise computed through ONE submitted ``submit_delta`` call
-merged with the redirect legs (they used to mutate recon chunks serially
-with unmodeled cost).  Concurrent engine calls in one phase contend for
-``CostModel.engine_depth`` lanes (default inf = the historical
-no-contention merge); the extra wait a finite depth induces is
-``stats["engine_queue_wait_s"]``.
+Coding carries a modeled cost (``CostModel.coding_s`` over
+``CodingEngine`` work bytes).  With ``async_engine=False`` (default,
+``$MEMEC_ASYNC``) it adds serially to a request's network phases; with
+``async_engine=True`` the store submits engine work while the phase's
+legs are modeled and charges ``max(coding, network)``, overlaps the seal
+fan-out with the SET acks, and runs the proxy lanes of a ``multi_*``
+request with ``proxy_id=None`` concurrently (``NetSim.merge_lanes``).
+Stored bytes are identical in both modes.  ``stats`` counts the overlap
+won (``intra_overlap_saved_s``, ``decode_overlap_saved_s`` — the decode
+share — and ``proxy_lane_saved_s`` against serially run lanes) and the
+wait for the engine's ``CostModel.engine_depth`` lanes
+(``engine_queue_wait_s``).
 """
 from __future__ import annotations
 
@@ -213,7 +200,8 @@ class MemECCluster:
         self.verify_rebuild = verify_rebuild
         self.failed: set[int] = set()          # injected transient failures
         self.redirect: dict[int, RedirectStore] = {}
-        # fault-injection hook: ("update"|"delete"|"set", key, parity_legs)
+        # fault-injection hook: ("update"|"delete", key, parity_legs) — the
+        # matching normal-mode request crashes after that many parity legs
         self.crash_hook: tuple | None = None
         self._stats = {"reconstructions": 0, "recon_chunk_hits": 0,
                       "reverted_deltas": 0, "degraded_requests": 0,
@@ -290,9 +278,6 @@ class MemECCluster:
     def _stripe_losses(self, sl: StripeList) -> int:
         """Chunks a stripe of ``sl`` has lost: its servers that are down."""
         return sum(self._is_failed(s) for s in sl.servers)
-
-    def _positions(self, sl: StripeList) -> list[int]:
-        return list(sl.servers)
 
     def _chunk_owner(self, sl: StripeList, position: int) -> int:
         return sl.servers[position]
@@ -388,10 +373,7 @@ class MemECCluster:
     # ------------------------------------------------------------------
     # normal-mode seal fan-out (data server -> parity servers)
     # ------------------------------------------------------------------
-    def _handle_seals(self, sl: StripeList, ds: int, events) -> float:
-        return self._handle_seals_batched([(sl, ds, ev) for ev in events])
-
-    def _handle_seals_batched(self, items: list[tuple]) -> float:
+    def _handle_seals(self, items: list[tuple]) -> float:
         """Fan seal events out to parity servers, folding each parity
         server's whole batch of rebuilt chunks through one engine call.
         ``items``: (stripe_list, data_server, SealEvent) triples — possibly
@@ -526,23 +508,19 @@ class MemECCluster:
         return self._set_small(key, value, proxy_id)
 
     def get(self, key: bytes, proxy_id: int = 0):
-        v = self._get_small(key, proxy_id)
-        total = large_total(v)
-        if total is not None:
-            return self._get_large(key, total, proxy_id)
-        return v
+        return self._get_large(key, self._get_small(key, proxy_id), proxy_id)
 
     def update(self, key: bytes, value: bytes, proxy_id: int = 0) -> bool:
         head = self._get_small(key, proxy_id)
         if head is not None and head.startswith(LARGE_MAGIC):
             return self._update_large(key, value, proxy_id)
-        return self._update_small(key, value, proxy_id)
+        return self._mutate_small("update", key, value, proxy_id)
 
     def delete(self, key: bytes, proxy_id: int = 0) -> bool:
         head = self._get_small(key, proxy_id)
         if head is not None and head.startswith(LARGE_MAGIC):
             return self._delete_large(key, head, proxy_id)
-        return self._delete_small(key, proxy_id)
+        return self._mutate_small("delete", key, None, proxy_id)
 
     # ------------------------------------------------------------------
     # batched multi-key API — amortizes coding (one engine call per
@@ -565,9 +543,10 @@ class MemECCluster:
             lanes.setdefault(pid, []).append(i)
         return sorted(lanes.items())
 
-    def _run_proxy_lanes(self, kind: str, keys, impl) -> list:
-        """``impl(idxs, pid) -> (results, t|None)``; results merge back in
-        request order, lane latencies merge into one facade record."""
+    def _run_proxy_lanes(self, kind: str, items, keys, impl) -> list:
+        """``impl(lane items, pid) -> (results, t|None)`` per lane;
+        results merge back in request order, lane latencies merge into
+        one facade record."""
         results: list = [None] * len(keys)
         dts: list[float] = []
         busys: list[dict] = []
@@ -577,7 +556,7 @@ class MemECCluster:
             b0 = self.net.busy_snapshot()
             if tr is not None:
                 tr.push()
-            res, t = impl(idxs, pid)
+            res, t = impl([items[i] for i in idxs], pid)
             segs = tr.pop() if tr is not None else None
             for i, v in zip(idxs, res):
                 results[i] = v
@@ -615,21 +594,26 @@ class MemECCluster:
         with span(spans.STORE_DEGRADED, ops=len(idxs)):
             return [request(i) for i in idxs]
 
+    def _run_multi(self, kind: str, items: list, keys: list, impl,
+                   proxy_id: int | None) -> list:
+        """One multi_* request: ``impl(items, pid) -> (results, t|None)``
+        runs it on proxy ``proxy_id`` or, with ``proxy_id=None``, on each
+        proxy lane its keys hash to; the time is recorded under ``kind``."""
+        if proxy_id is None and self.num_proxies > 1 and len(items) > 1:
+            return self._run_proxy_lanes(kind, items, keys, impl)
+        tr = self._trace_frame()
+        out, t = impl(items, proxy_id or 0)
+        if t is not None:
+            self.net.record(kind, t)
+        elif tr is not None:
+            tr.cancel()
+        return out
+
     def multi_get(self, keys, proxy_id: int | None = 0) -> list:
         keys = list(keys)
         with span(spans.STORE_MULTI_GET, ops=len(keys)):
-            if proxy_id is None and self.num_proxies > 1 and len(keys) > 1:
-                return self._run_proxy_lanes(
-                    "MGET", keys,
-                    lambda idxs, pid: self._multi_get_impl(
-                        [keys[i] for i in idxs], pid))
-            tr = self._trace_frame()
-            out, t = self._multi_get_impl(keys, proxy_id or 0)
-            if t is not None:
-                self.net.record("MGET", t)
-            elif tr is not None:
-                tr.cancel()
-            return out
+            return self._run_multi("MGET", keys, keys, self._multi_get_impl,
+                                   proxy_id)
 
     def _multi_get_impl(self, keys, proxy_id: int):
         proxy = self.proxies[proxy_id]
@@ -646,44 +630,17 @@ class MemECCluster:
             out[i] = v
         t = None
         if plan:
-            if self.redundant_reads > 0 and self.code.m > 0:
-                vals, t = self._coded_read_batch(
-                    proxy, [(key, sl, ds) for _, key, sl, ds in plan])
-                for (i, _, _, _), v in zip(plan, vals):
-                    out[i] = v
-            else:
-                t = self.net.phase([Leg("get", len(key), f"p{proxy.pid}",
-                                        f"s{ds}", self._is_failed(ds))
-                                    for _, key, _, ds in plan])
-                resp_legs = []
-                for i, key, _, ds in plan:
-                    v = self._sv(ds).get_value(key)
-                    resp_legs.append(Leg("get_resp", len(v) if v else 0,
-                                         f"s{ds}", f"p{proxy.pid}",
-                                         self._is_failed(ds)))
-                    out[i] = v
-                t += self.net.phase(resp_legs)
-            for i, key, _, ds in plan:  # large objects: fetch fragments
-                total = large_total(out[i])
-                if total is not None:
-                    out[i] = self._get_large(key, total, proxy_id)
+            vals, t = self._get_batch(
+                proxy, [(key, sl, ds) for _, key, sl, ds in plan])
+            for (i, key, _, _), v in zip(plan, vals):
+                out[i] = self._get_large(key, v, proxy_id)
         return out, t
 
     def multi_set(self, items, proxy_id: int | None = 0) -> list[bool]:
         items = list(items)
         with span(spans.STORE_MULTI_SET, ops=len(items)):
-            if proxy_id is None and self.num_proxies > 1 and len(items) > 1:
-                return self._run_proxy_lanes(
-                    "MSET", [k for k, _ in items],
-                    lambda idxs, pid: self._multi_set_impl(
-                        [items[i] for i in idxs], pid))
-            tr = self._trace_frame()
-            ok, t = self._multi_set_impl(items, proxy_id or 0)
-            if t is not None:
-                self.net.record("MSET", t)
-            elif tr is not None:
-                tr.cancel()
-            return ok
+            return self._run_multi("MSET", items, [k for k, _ in items],
+                                   self._multi_set_impl, proxy_id)
 
     def _multi_set_impl(self, items, proxy_id: int):
         proxy = self.proxies[proxy_id]
@@ -702,40 +659,10 @@ class MemECCluster:
                 batch.append((i, key, value, sl, ds))
         t = None
         if batch:
-            t = 0.0
-            reqs, legs = [], []
-            for i, key, value, sl, ds in batch:
-                reqs.append(proxy.begin("SET", key, value, sl, ds))
-                obj = object_size(len(key), len(value))
-                legs.append(Leg("set", obj, f"p{proxy.pid}", f"s{ds}",
-                                self._is_failed(ds)))
-                legs += [Leg("set_replica", obj, f"p{proxy.pid}", f"s{p}",
-                             self._is_failed(p)) for p in sl.parity_servers]
-            t += self.net.phase(legs)
-            seal_items, ack_legs, touched = [], [], []
-            for (i, key, value, sl, ds), req in zip(batch, reqs):
-                cid, off, events = self._sv(ds).set_object(sl, key, value)
-                iseq = self._sv(ds).live_iseq(key)
-                for p in sl.parity_servers:
-                    self._sv(p).store_replica(key, value, iseq=iseq)
-                seal_items += [(sl, ds, ev) for ev in events]
-                ack_legs.append(Leg("set_ack", len(key) + 8, f"s{ds}",
-                                    f"p{proxy.pid}", self._is_failed(ds)))
-                ack_legs += [Leg("set_ack", 8, f"s{p}", f"p{proxy.pid}",
-                                 self._is_failed(p))
-                             for p in sl.parity_servers]
-                proxy.buffer_mapping(ds, key, cid, iseq)
-                touched.append(ds)
+            t = self._set_batch(proxy, [proxy.begin("SET", key, value, sl, ds)
+                                        for _, key, value, sl, ds in batch])
+            for i, *_ in batch:
                 ok[i] = True
-            # async: the seal fan-out (parity rebuild + fold) overlaps
-            # the SET acknowledgements already in flight
-            t += self._overlap_branches(
-                ("seal", lambda: self._handle_seals_batched(seal_items)),
-                ("ack", lambda: self.net.phase(ack_legs)))
-            for req in reqs:
-                proxy.ack(req.seq)
-            for ds in dict.fromkeys(touched):
-                t += self._maybe_checkpoint(ds)
         for i, key, value in deferred:   # duplicate keys: now upserts
             ok[i] = self.set(key, value, proxy_id)
         return ok, t
@@ -743,32 +670,19 @@ class MemECCluster:
     def multi_update(self, items, proxy_id: int | None = 0) -> list[bool]:
         items = list(items)
         with span(spans.STORE_MULTI_UPDATE, ops=len(items)):
-            if self.crash_hook is not None and self.crash_hook[0] == "update":
+            hook = self.crash_hook
+            if hook is not None and hook[0] == "update":
                 # fault injection must fire exactly as in sequential mode:
                 # everything before the crashing key completes first, the
                 # crash raises, and nothing after it executes
-                hook_i = next((i for i, (k, _) in enumerate(items)
-                               if k == self.crash_hook[1]), None)
-                if hook_i is not None:
-                    hook_pid = proxy_id if proxy_id is not None else 0
-                    ok = [False] * len(items)
-                    ok[:hook_i] = self.multi_update(items[:hook_i], proxy_id)
-                    ok[hook_i] = self.update(*items[hook_i], hook_pid)
-                    ok[hook_i + 1:] = self.multi_update(items[hook_i + 1:],
-                                                        proxy_id)
-                    return ok
-            if proxy_id is None and self.num_proxies > 1 and len(items) > 1:
-                return self._run_proxy_lanes(
-                    "MUPDATE", [k for k, _ in items],
-                    lambda idxs, pid: self._multi_update_impl(
-                        [items[i] for i in idxs], pid))
-            tr = self._trace_frame()
-            ok, t = self._multi_update_impl(items, proxy_id or 0)
-            if t is not None:
-                self.net.record("MUPDATE", t)
-            elif tr is not None:
-                tr.cancel()
-            return ok
+                c = next((i for i, (k, _) in enumerate(items)
+                          if k == hook[1]), None)
+                if c is not None:
+                    ok = self.multi_update(items[:c], proxy_id)
+                    ok.append(self.update(*items[c], proxy_id or 0))
+                    return ok + self.multi_update(items[c + 1:], proxy_id)
+            return self._run_multi("MUPDATE", items, [k for k, _ in items],
+                                   self._multi_update_impl, proxy_id)
 
     def _multi_update_impl(self, items, proxy_id: int):
         proxy = self.proxies[proxy_id]
@@ -801,144 +715,275 @@ class MemECCluster:
             # head-probe round trip (sequential update() pays a modeled
             # GET per key before choosing the update path — charge the
             # batched equivalent so MUPDATE stays comparable)
-            t = self.net.phase([Leg("get", len(key), f"p{proxy.pid}",
-                                    f"s{ds}", self._is_failed(ds))
+            pp = f"p{proxy.pid}"
+            t = self.net.phase([Leg("get", len(key), pp, f"s{ds}",
+                                    self._is_failed(ds))
                                 for _, key, _, _, ds, _ in batch])
-            t += self.net.phase([Leg("get_resp",
-                                     len(head) if head else 0, f"s{ds}",
-                                     f"p{proxy.pid}", self._is_failed(ds))
+            t += self.net.phase([Leg("get_resp", len(head) if head else 0,
+                                     f"s{ds}", pp, self._is_failed(ds))
                                  for _, _, _, _, ds, head in batch])
-            t += self.net.phase([Leg("update", len(key) + len(value),
-                                     f"p{proxy.pid}", f"s{ds}",
-                                     self._is_failed(ds))
-                                 for _, key, value, _, ds, _ in batch])
-            sealed_jobs, replica_jobs, done_reqs = [], [], []
-            for i, key, value, sl, ds, _head in batch:
-                req = proxy.begin("UPDATE", key, value, sl, ds)
-                res = self._sv(ds).update_value(key, value)
-                if res is None:
-                    proxy.ack(req.seq)
-                    continue
-                cid, sealed, off, xor = res
-                nz = np.nonzero(xor)[0]
-                if len(nz):
-                    seg_off = off + int(nz[0])
-                    seg = xor[int(nz[0]): int(nz[-1]) + 1]
-                else:
-                    seg_off, seg = off, xor[:0]
-                if sealed:
-                    if (self._hot_eligible() and self._hot_buffer_update(
-                            key, sl, ds, cid, seg_off, seg)):
-                        pass   # hot key: parity round deferred to flush
-                    else:
-                        sealed_jobs.append((sl, ds, cid, seg_off, seg, req))
-                else:
-                    replica_jobs.append((sl, ds, key, value, req))
-                done_reqs.append(req)
-                ok[i] = True
-            legs = []
-            fut = None
-            old_par = None
-            if sealed_jobs:
-                # one *submitted* engine call computes AND folds every
-                # parity row of every updated chunk (fused delta+apply —
-                # no separate (B, m, C) delta materialization); the delta
-                # legs are modeled while it is in flight
-                with span(spans.STORE_PARITY_GATHER):
-                    fulls = np.zeros((len(sealed_jobs), self.chunk_size),
-                                     np.uint8)
-                    for b, (sl, ds, cid, seg_off, seg, req) in enumerate(
-                            sealed_jobs):
-                        fulls[b, seg_off: seg_off + len(seg)] = seg
-                    positions = np.array(
-                        [cid.position for _, _, cid, _, _, _ in sealed_jobs])
-                    old_par = np.stack(
-                        [np.stack([self._sv(p).parity_row(sl, cid.stripe_id)
-                                   for p in sl.parity_servers])
-                         for sl, ds, cid, _, _, _ in sealed_jobs])
-                fut = self.engine.submit_apply_delta(old_par, positions,
-                                                     fulls)
-                for sl, ds, cid, seg_off, seg, req in sealed_jobs:
-                    legs += [Leg("delta", len(seg), f"s{ds}", f"s{p}",
-                                 self._is_failed(p))
-                             for p in sl.parity_servers]
-            for sl, ds, key, value, req in replica_jobs:
-                for p in sl.parity_servers:
-                    self._sv(p).apply_replica_delta(key, value, False,
-                                                    proxy.pid, req.seq)
-                    legs.append(Leg("replica_delta", len(key) + len(value),
-                                    f"s{ds}", f"s{p}", self._is_failed(p)))
-            net_t = self.net.phase(legs) if legs else 0.0
-            if fut is not None:
-                # per-row deltas (new ^ old) feed the §5.3 revert buffer;
-                # extraction is stale-proof even when two jobs share a
-                # stripe's parity slot — the delta never depends on the
-                # gathered parity content
-                with span(spans.STORE_DELTA_LOG):
-                    deltas = fut.result() ^ old_par
-                    for (sl, ds, cid, seg_off, seg, req), delta in zip(
-                            sealed_jobs, deltas):
-                        for j, p in enumerate(sl.parity_servers):
-                            self._sv(p).apply_data_delta_row(
-                                sl, cid, delta[j], proxy.pid, req.seq)
-            if legs or fut is not None:
-                t += self._merge_coding(self._coding_s(fut), net_t,
-                                        kind="delta")
-            t += self.net.phase([Leg("update_ack", 8, f"s{ds}",
-                                     f"p{proxy.pid}", self._is_failed(ds))
-                                 for _, _, _, _, ds, _ in batch])
-            parity_set = {p for _, _, _, sl, _, _ in batch
-                          for p in sl.parity_servers}
-            for req in done_reqs:
-                proxy.ack(req.seq)
-            for p in parity_set:
-                self._sv(p).prune_deltas(proxy.pid, proxy.ack_watermark)
+            done, t = self._mutate_batch("update", proxy, [
+                (key, value, sl, ds) for _, key, value, sl, ds, _ in batch], t)
+            for (i, *_), d in zip(batch, done):
+                ok[i] = d
         for i, key, value in deferred:
             ok[i] = self.update(key, value, proxy_id)
         return ok, t
 
     # ------------------------------------------------------------------
-    # SET
+    # normal-mode cores: classified entries of one proxy in, modeled time
+    # out; the caller records it (GET.. for a single key, MGET.. batched)
     # ------------------------------------------------------------------
+    def _get_batch(self, proxy: Proxy, entries) -> tuple[list, float]:
+        """GETs of ``entries`` (key, stripe list, data server).  With
+        ``redundant_reads`` the straggler-tolerant k-of-(k+Δ) race
+        answers instead: byte-identical values, only who answers
+        differs."""
+        if self.redundant_reads > 0 and self.code.m > 0:
+            return self._coded_read_batch(proxy, entries)
+        pp = f"p{proxy.pid}"
+        t = self.net.phase([Leg("get", len(key), pp, f"s{ds}",
+                                self._is_failed(ds))
+                            for key, _, ds in entries])
+        vals = [self._sv(ds).get_value(key) for key, _, ds in entries]
+        t += self.net.phase([Leg("get_resp", len(v) if v else 0, f"s{ds}",
+                                 pp, self._is_failed(ds))
+                             for v, (_, _, ds) in zip(vals, entries)])
+        return vals, t
+
+    def _set_batch(self, proxy: Proxy, reqs) -> float:
+        """Fresh SETs of begun requests (``proxy.begin`` results carry
+        the key, value, stripe list and data server)."""
+        pp = f"p{proxy.pid}"
+        legs = []
+        for req in reqs:
+            obj = object_size(len(req.key), len(req.value))
+            legs.append(Leg("set", obj, pp, f"s{req.data_server}",
+                            self._is_failed(req.data_server)))
+            legs += [Leg("set_replica", obj, pp, f"s{p}", self._is_failed(p))
+                     for p in req.stripe_list.parity_servers]
+        t = self.net.phase(legs)
+        seal_items, ack_legs = [], []
+        for req in reqs:
+            key, value = req.key, req.value
+            sl, ds = req.stripe_list, req.data_server
+            cid, _, events = self._sv(ds).set_object(sl, key, value)
+            iseq = self._sv(ds).live_iseq(key)
+            for p in sl.parity_servers:
+                self._sv(p).store_replica(key, value, iseq=iseq)
+            seal_items += [(sl, ds, ev) for ev in events]
+            # the data server's ack piggybacks the key->chunk-ID mapping
+            ack_legs.append(Leg("set_ack", len(key) + 8, f"s{ds}", pp,
+                                self._is_failed(ds)))
+            ack_legs += [Leg("set_ack", 8, f"s{p}", pp, self._is_failed(p))
+                         for p in sl.parity_servers]
+            proxy.buffer_mapping(ds, key, cid, iseq)
+        # async: the seal fan-out (parity rebuild + fold) overlaps the SET
+        # acknowledgements already in flight
+        t += self._overlap_branches(
+            ("seal", lambda: self._handle_seals(seal_items)),
+            ("ack", lambda: self.net.phase(ack_legs)))
+        for req in reqs:
+            proxy.ack(req.seq)
+        for ds in dict.fromkeys(req.data_server for req in reqs):
+            t += self._maybe_checkpoint(ds)
+        return t
+
+    def _mutate_batch(self, kind: str, proxy: Proxy, entries,
+                      t: float = 0.0) -> tuple[list[bool], float]:
+        """UPDATEs (``kind="update"``) or DELETEs of ``entries`` (key,
+        value or None, stripe list, data server); returns each one's
+        answer and the modeled time, added on to ``t`` (the time the
+        caller's head probe took).  Sealed objects share ONE parity-delta
+        round, a hot key's sealed UPDATE buffers its delta instead
+        (``_hot_buffer_update``), unsealed objects patch the parity
+        replicas.  A missing key answers False and still takes its ack
+        leg."""
+        pp = f"p{proxy.pid}"
+        t += self.net.phase([Leg(kind, len(key) + (len(value) if value else 0),
+                                 pp, f"s{ds}", self._is_failed(ds))
+                             for key, value, _, ds in entries])
+        ok, done, sealed_jobs, replica_jobs = [], [], [], []
+        for key, value, sl, ds in entries:
+            req = proxy.begin(kind.upper(), key, value, sl, ds)
+            srv = self._sv(ds)
+            res = (srv.update_value(key, value) if kind == "update"
+                   else srv.delete_object(key))
+            ok.append(res is not None)
+            if res is None:
+                proxy.ack(req.seq)
+                continue
+            done.append(req)
+            cid, sealed, off, xor = res
+            seg_off, seg = self._changed_extent(off, xor)
+            if not sealed:
+                replica_jobs.append(req)
+            elif self.code.m > 0 and not (
+                    kind == "update" and self._hot_eligible()
+                    and self._hot_buffer_update(key, sl, ds, cid, seg_off,
+                                                seg)):
+                sealed_jobs.append((req, cid, seg_off, seg))
+        legs, fut = [], None
+        if sealed_jobs:
+            # one *submitted* engine call computes AND folds every parity
+            # row of every updated chunk (fused delta+apply — no separate
+            # (B, m, C) delta materialization); the delta legs are
+            # modeled while it is in flight
+            with span(spans.STORE_PARITY_GATHER):
+                fulls = np.zeros((len(sealed_jobs), self.chunk_size), np.uint8)
+                for b, (_, _, seg_off, seg) in enumerate(sealed_jobs):
+                    fulls[b, seg_off: seg_off + len(seg)] = seg
+                positions = np.array([c.position for _, c, *_ in sealed_jobs])
+                old_par = self._gather_parity(
+                    [(req.stripe_list, cid) for req, cid, _, _ in sealed_jobs])
+            fut = self.engine.submit_apply_delta(old_par, positions, fulls)
+            for req, _, _, seg in sealed_jobs:
+                legs += [Leg("delta", len(seg), f"s{req.data_server}",
+                             f"s{p}", self._is_failed(p))
+                         for p in req.stripe_list.parity_servers]
+        for req in replica_jobs:
+            nv = req.value if kind == "update" else b""
+            for _, p in self._parity_legs(req):
+                self._sv(p).apply_replica_delta(req.key, nv, kind == "delete",
+                                                proxy.pid, req.seq)
+                legs.append(Leg("replica_delta", len(req.key) + len(nv),
+                                f"s{req.data_server}", f"s{p}",
+                                self._is_failed(p)))
+        net_t = self.net.phase(legs) if legs else 0.0
+        if fut is not None:
+            # per-row deltas (new ^ old) feed the §5.3 revert buffer; they
+            # stay exact when two jobs share a stripe's parity slot — the
+            # delta never depends on the gathered parity content
+            with span(spans.STORE_DELTA_LOG):
+                deltas = fut.result() ^ old_par
+                for (req, cid, _, _), rows in zip(sealed_jobs, deltas):
+                    self._apply_parity_rows(self._parity_legs(req),
+                                            req.stripe_list, cid, rows,
+                                            proxy.pid, req.seq)
+        if legs or fut is not None:
+            t += self._merge_coding(self._coding_s(fut), net_t, kind="delta")
+        t += self.net.phase([Leg(f"{kind}_ack", 8, f"s{ds}", pp,
+                                 self._is_failed(ds))
+                             for _, _, _, ds in entries])
+        for req in done:
+            proxy.ack(req.seq)
+        # parity servers prune delta buffers using the ack watermark (§5.3)
+        for p in {p for _, _, sl, _ in entries for p in sl.parity_servers}:
+            self._sv(p).prune_deltas(proxy.pid, proxy.ack_watermark)
+        return ok, t
+
+    @staticmethod
+    def _changed_extent(off: int, xor: np.ndarray) -> tuple[int, np.ndarray]:
+        """An object's xor trimmed to its nonzero extent (what crosses
+        the wire): ``(chunk offset, segment)``."""
+        nz = np.nonzero(xor)[0]
+        if not len(nz):
+            return off, xor[:0]
+        return off + int(nz[0]), xor[int(nz[0]): int(nz[-1]) + 1]
+
+    def _gather_parity(self, stripes) -> np.ndarray:
+        """(B, m, C) parity rows of ``stripes`` ((stripe list, chunk id)
+        pairs): the kernel's parity input."""
+        return np.stack([np.stack([self._sv(p).parity_row(sl, cid.stripe_id)
+                                   for p in sl.parity_servers])
+                         for sl, cid in stripes])
+
+    def _apply_parity_rows(self, legs, sl: StripeList, cid: ChunkId,
+                           rows: np.ndarray, pid: int, seq: int) -> None:
+        """Fold one chunk's per-parity delta rows into the parity servers
+        that ``legs`` enumerates as (row, server); each server keeps its
+        row for the §5.3 revert."""
+        for j, p in legs:
+            self._sv(p).apply_data_delta_row(sl, cid, rows[j], pid, seq)
+
+    def _parity_legs(self, req):
+        """Enumerate a request's parity servers as its delta legs reach
+        them.  Fault injection fires here: with ``crash_hook`` armed for
+        the request's kind and key, its data server crashes after
+        ``crash_hook[2]`` legs, leaving the request unacknowledged."""
+        hook = self.crash_hook
+        stop = (hook[2] if hook is not None
+                and hook[:2] == (req.kind.lower(), req.key) else None)
+        for j, p in enumerate(req.stripe_list.parity_servers):
+            if j == stop:
+                self.crash_hook = None
+                raise PartialFailure(f"data server {req.data_server} "
+                                     f"crashed after {j} parity legs")
+            yield j, p
+
+    # ------------------------------------------------------------------
+    # single-key routing: a coordinated key takes the degraded path
+    # (§5.4), any other is a batch of one through the core
+    # ------------------------------------------------------------------
+    def _get_small(self, key: bytes, proxy_id: int):
+        proxy = self.proxies[proxy_id]
+        sl, ds = self.mapper.data_server_for(key)
+        if self._coordinated(ds):
+            return self._degraded_get(proxy, sl, ds, key)
+        self._trace_frame()
+        vals, t = self._get_batch(proxy, [(key, sl, ds)])
+        self.net.record("GET", t)
+        return vals[0]
+
     def _set_small(self, key: bytes, value: bytes, proxy_id: int):
         proxy = self.proxies[proxy_id]
         sl, ds = self.mapper.data_server_for(key)
         if self._coordinated(ds, *sl.parity_servers):
             return self._degraded_set(proxy, sl, ds, key, value)
+        # begun before the upsert below, whose DELETE takes the next seq
         req = proxy.begin("SET", key, value, sl, ds)
-        t = 0.0
         # upsert: a key must never occupy two chunk slots (see module doc)
-        if self._sv(ds).lookup(key) is not None:
-            ref = self._sv(ds).lookup(key)
+        ref = self._sv(ds).lookup(key)
+        if ref is not None:
             if ref.value_size == len(value):
                 proxy.ack(req.seq)
-                return self._update_small(key, value, proxy_id)
-            self._delete_small(key, proxy_id)
+                return self._mutate_small("update", key, value, proxy_id)
+            self._mutate_small("delete", key, None, proxy_id)
         self._trace_frame()
-        obj_bytes = object_size(len(key), len(value))
-        legs = [Leg("set", obj_bytes, f"p{proxy.pid}", f"s{ds}", self._is_failed(ds))]
-        for p in sl.parity_servers:
-            legs.append(Leg("set_replica", obj_bytes, f"p{proxy.pid}", f"s{p}",
-                            self._is_failed(p)))
-        t += self.net.phase(legs)
-        cid, off, seal_events = self._sv(ds).set_object(sl, key, value)
-        iseq = self._sv(ds).live_iseq(key)
-        for p in sl.parity_servers:
-            self._sv(p).store_replica(key, value, iseq=iseq)
-        # acks (data server piggybacks the key->chunk-ID mapping, §5.3);
-        # async overlaps the seal fan-out with the acks in flight
-        ack_legs = [Leg("set_ack", len(key) + 8, f"s{ds}", f"p{proxy.pid}",
-                        self._is_failed(ds))]
-        ack_legs += [Leg("set_ack", 8, f"s{p}", f"p{proxy.pid}", self._is_failed(p))
-                     for p in sl.parity_servers]
-        t += self._overlap_branches(
-            ("seal", lambda: self._handle_seals(sl, ds, seal_events)),
-            ("ack", lambda: self.net.phase(ack_legs)))
-        proxy.buffer_mapping(ds, key, cid, iseq)
-        t += self._maybe_checkpoint(ds)
-        proxy.ack(req.seq)
-        self.net.record("SET", t)
+        self.net.record("SET", self._set_batch(proxy, [req]))
         return True
+
+    def _mutate_small(self, kind: str, key: bytes, value: bytes | None,
+                      proxy_id: int) -> bool:
+        proxy = self.proxies[proxy_id]
+        sl, ds = self.mapper.data_server_for(key)
+        if self._coordinated(ds, *sl.parity_servers):
+            return self._degraded_mutate(kind, proxy, sl, ds, key, value)
+        self._trace_frame()
+        ok, t = self._mutate_batch(kind, proxy, [(key, value, sl, ds)])
+        self.net.record(kind.upper(), t)
+        return ok[0]
+
+    def _update_large(self, key: bytes, value: bytes, proxy_id: int) -> bool:
+        frags = split_fragments(key, value, self.chunk_size)
+        ok = True
+        for fkey, fval in frags:
+            ok &= self._mutate_small("update", fkey, fval, proxy_id)
+        return ok
+
+    def _delete_large(self, key: bytes, head: bytes, proxy_id: int) -> bool:
+        total = large_total(head)
+        nfrag = fragment_count(total, len(key), self.chunk_size)
+        for i in range(nfrag):
+            self._mutate_small("delete", key + struct.pack("<I", i), None,
+                               proxy_id)
+        return self._mutate_small("delete", key, None, proxy_id)
+
+    def _get_large(self, key: bytes, head: bytes | None, proxy_id: int):
+        """The object ``head`` answers for: itself, or, for a large
+        object's manifest, its fragments fetched and joined."""
+        total = large_total(head)
+        if total is None:
+            return head
+        nfrag = fragment_count(total, len(key), self.chunk_size)
+        parts = []
+        for i in range(nfrag):
+            fkey = key + struct.pack("<I", i)
+            part = self._get_small(fkey, proxy_id)
+            if part is None:
+                return None
+            parts.append(part)
+        return b"".join(parts)[:total]
 
     def _set_large(self, key: bytes, value: bytes, proxy_id: int):
         frags = split_fragments(key, value, self.chunk_size)
@@ -948,7 +993,7 @@ class MemECCluster:
         return self._set_small(key, manifest, proxy_id)
 
     # ------------------------------------------------------------------
-    # GET
+    # straggler-tolerant GET
     # ------------------------------------------------------------------
     def _endpoint_load(self, sid: int) -> float:
         """Load-aware chunk selection score for one server: cumulative
@@ -965,6 +1010,23 @@ class MemECCluster:
         if self.net.events is not None:
             load += self.net.events.link_free.get(ep, 0.0)
         return load
+
+    def _race(self, pp: str, key: bytes, primary, servers: list[int],
+              resp_bytes: int, need: int) -> tuple[float, list[int]]:
+        """Race the ``primary`` round trip against a ``resp_bytes`` fetch
+        from each of ``servers``; complete at the ``need``-th arrival.
+        Returns the phase time and the winning group indexes (0 is the
+        primary, ``i`` is ``servers[i - 1]``)."""
+        groups = [primary] + [
+            (f"rget:{pp}->s{s}",
+             [Leg("rget", len(key), pp, f"s{s}", self._is_failed(s)),
+              Leg("rget_resp", resp_bytes, f"s{s}", pp, self._is_failed(s))])
+            for s in servers]
+        if servers:
+            self._stats["redundant_reads"] += 1
+        t, winners, dropped = self.net.race_phase(groups, need=need)
+        self._stats["redundant_cancelled"] += len(dropped)
+        return t, winners
 
     def _coded_read_batch(self, proxy, entries):
         """Straggler-tolerant k-of-(k+Δ) GET fan-out (Hydra-style late
@@ -999,13 +1061,15 @@ class MemECCluster:
             # read barrier: the sealed races below may read parity
             # chunks of these stripes — collapse any buffered hot-key
             # deltas owed to them first, so decode sees consistent parity
-            stripes = []
+            drained = []
             for key, sl, ds in entries:
                 srv = self._sv(ds)
                 ref = srv.lookup(key)
                 if ref is not None and srv.sealed[ref.chunk_local_idx]:
-                    stripes.append((sl, srv.chunk_id_of(ref)))
-            self._hot_barrier_stripes(stripes)
+                    drained += self.hot.buffer.pop_stripe(
+                        sl, srv.chunk_id_of(ref))
+            if drained:
+                self._flush_hot_entries(drained, barrier=True)
         delta = self.redundant_reads
         pp = f"p{proxy.pid}"
         vals: list = [None] * len(entries)
@@ -1025,32 +1089,20 @@ class MemECCluster:
                         Leg("get_resp", vsz, f"s{ds}", pp, failed_ds)])
             if ref is None:
                 # miss/deleted: one round trip, cost-identical to plain
-                t, _, _ = self.net.race_phase([primary], need=1)
-                race_ts.append(t)
+                race_ts.append(self._race(pp, key, primary, [], 0, 1)[0])
                 vals[slot] = v
                 continue
             if not srv.sealed[ref.chunk_local_idx]:
                 # unsealed: replicated at every alive parity server
-                cands = sorted(
+                cands = [p for _, p in sorted(
                     (self._endpoint_load(p), p) for p in sl.parity_servers
-                    if not self._coordinated(p))
-                cands = cands[:delta]
-                groups = [primary]
-                for _, p in cands:
-                    fp = self._is_failed(p)
-                    groups.append(
-                        (f"rget:{pp}->s{p}",
-                         [Leg("rget", len(key), pp, f"s{p}", fp),
-                          Leg("rget_resp", vsz, f"s{p}", pp, fp)]))
-                if len(groups) > 1:
-                    self._stats["redundant_reads"] += 1
-                t, winners, dropped = self.net.race_phase(groups, need=1)
+                    if not self._coordinated(p))][:delta]
+                t, winners = self._race(pp, key, primary, cands, vsz, 1)
                 race_ts.append(t)
-                self._stats["redundant_cancelled"] += len(dropped)
                 if winners == [0]:
                     vals[slot] = v
                 else:
-                    rep = self._sv(cands[winners[0] - 1][1]).get_replica(key)
+                    rep = self._sv(cands[winners[0] - 1]).get_replica(key)
                     if rep is None:
                         self._stats["redundant_replica_fallbacks"] += 1
                         vals[slot] = v
@@ -1066,23 +1118,12 @@ class MemECCluster:
                 (self._endpoint_load(owner), i >= self.k, i)
                 for i, owner in enumerate(sl.servers)
                 if i != pos and not self._coordinated(owner))
-            take = cand_pos[: self.k - 1 + delta]
-            groups, members = [primary], [pos]
-            for _, _, i in take:
-                owner = self._chunk_owner(sl, i)
-                fo = self._is_failed(owner)
-                groups.append(
-                    (f"rget:{pp}->s{owner}",
-                     [Leg("rget", len(key), pp, f"s{owner}", fo),
-                      Leg("rget_resp", self.chunk_size, f"s{owner}", pp,
-                          fo)]))
-                members.append(i)
-            if len(groups) > 1:
-                self._stats["redundant_reads"] += 1
-            t, winners, dropped = self.net.race_phase(
-                groups, need=min(self.k, len(groups)))
+            members = [pos] + [i for _, _, i in cand_pos[: self.k - 1 + delta]]
+            t, winners = self._race(
+                pp, key, primary,
+                [self._chunk_owner(sl, i) for i in members[1:]],
+                self.chunk_size, self.k)
             race_ts.append(t)
-            self._stats["redundant_cancelled"] += len(dropped)
             if 0 in winners:
                 vals[slot] = v
             else:
@@ -1119,41 +1160,6 @@ class MemECCluster:
                     f"race decode diverged for {key!r}"
         return vals, t_total
 
-    def _get_small(self, key: bytes, proxy_id: int):
-        proxy = self.proxies[proxy_id]
-        sl, ds = self.mapper.data_server_for(key)
-        if self._coordinated(ds):
-            return self._degraded_get(proxy, sl, ds, key)
-        if self.redundant_reads > 0 and self.code.m > 0:
-            # straggler-tolerant k-of-(k+Δ) read (contents byte-identical
-            # to the plain path; only the who-answers race differs)
-            self._trace_frame()
-            vals, t = self._coded_read_batch(proxy, [(key, sl, ds)])
-            self.net.record("GET", t)
-            return vals[0]
-        self._trace_frame()
-        t = self.net.phase([Leg("get", len(key), f"p{proxy.pid}", f"s{ds}",
-                                self._is_failed(ds))])
-        v = self._sv(ds).get_value(key)
-        t += self.net.phase([Leg("get_resp", len(v) if v else 0, f"s{ds}",
-                                 f"p{proxy.pid}", self._is_failed(ds))])
-        self.net.record("GET", t)
-        return v
-
-    def _get_large(self, key: bytes, total: int, proxy_id: int):
-        nfrag = fragment_count(total, len(key), self.chunk_size)
-        parts = []
-        for i in range(nfrag):
-            fkey = key + struct.pack("<I", i)
-            part = self._get_small(fkey, proxy_id)
-            if part is None:
-                return None
-            parts.append(part)
-        return b"".join(parts)[:total]
-
-    # ------------------------------------------------------------------
-    # UPDATE / DELETE (shared delta fan-out)
-    # ------------------------------------------------------------------
     # ------------------------------------------------------------------
     # hot-key update tier (version-buffered delta coding)
     # ------------------------------------------------------------------
@@ -1198,18 +1204,6 @@ class MemECCluster:
             self._flush_hot_entries(flush_now)
         return True
 
-    def _hot_barrier_stripes(self, stripe_entries) -> None:
-        """Read barrier: before any sealed-chunk race/decode touches a
-        stripe's parity, collapse that stripe's buffered deltas back in
-        (``stripe_entries``: iterable of (sl, cid))."""
-        if self.hot is None or not len(self.hot.buffer):
-            return
-        drained = []
-        for sl, cid in stripe_entries:
-            drained += self.hot.buffer.pop_stripe(sl, cid)
-        if drained:
-            self._flush_hot_entries(drained, barrier=True)
-
     def _flush_hot_entries(self, entries, *, barrier: bool = False) -> float:
         """Fold buffered version deltas back into their sealed stripes.
 
@@ -1230,9 +1224,7 @@ class MemECCluster:
         proxy = self.proxies[0]
         self._trace_frame()
         C = self.chunk_size
-        parity = np.stack(
-            [np.stack([self._sv(p).parity_row(e.sl, e.cid.stripe_id)
-                       for p in e.sl.parity_servers]) for e in entries])
+        parity = self._gather_parity([(e.sl, e.cid) for e in entries])
         positions = np.array([e.cid.position for e in entries])
         version_xors, legs = [], []
         for e in entries:
@@ -1250,9 +1242,9 @@ class MemECCluster:
         rows = fut.result() ^ parity
         wm = proxy.ack_watermark
         for e, erows in zip(entries, rows):
-            for j, p in enumerate(e.sl.parity_servers):
-                self._sv(p).apply_data_delta_row(e.sl, e.cid, erows[j],
-                                                 proxy.pid, wm)
+            self._apply_parity_rows(enumerate(e.sl.parity_servers), e.sl,
+                                    e.cid, erows, proxy.pid, wm)
+            for p in e.sl.parity_servers:
                 self._sv(p).prune_deltas(proxy.pid, wm)
             m = len(e.sl.parity_servers)
             lo, hi = e.extent()
@@ -1279,111 +1271,6 @@ class MemECCluster:
         entries = self.hot.buffer.pop_all()
         self._flush_hot_entries(entries)
         return len(entries)
-
-    def _mutate_small(self, kind: str, key: bytes, value: bytes | None,
-                      proxy_id: int) -> bool:
-        proxy = self.proxies[proxy_id]
-        sl, ds = self.mapper.data_server_for(key)
-        if self._coordinated(ds, *sl.parity_servers):
-            return self._degraded_mutate(kind, proxy, sl, ds, key, value)
-        self._trace_frame()
-        req = proxy.begin(kind.upper(), key, value, sl, ds)
-        t = self.net.phase([Leg(kind, len(key) + (len(value) if value else 0),
-                                f"p{proxy.pid}", f"s{ds}", self._is_failed(ds))])
-        srv = self._sv(ds)
-        if kind == "update":
-            res = srv.update_value(key, value)
-        else:
-            res = srv.delete_object(key)
-        if res is None:
-            proxy.ack(req.seq)
-            self.net.record(kind.upper(), t)
-            return False
-        cid, sealed, off, xor = res
-        # trim the xor to its nonzero extent (what crosses the wire)
-        nz = np.nonzero(xor)[0]
-        if len(nz):
-            seg_off, seg = off + int(nz[0]), xor[int(nz[0]): int(nz[-1]) + 1]
-        else:
-            seg_off, seg = off, xor[:0]
-        crash = (self.crash_hook is not None and self.crash_hook[0] == kind
-                 and self.crash_hook[1] == key)
-        if (kind == "update" and sealed and self._hot_eligible()
-                and self._hot_buffer_update(key, sl, ds, cid, seg_off,
-                                            seg)):
-            # hot key: the version delta is buffered and the parity
-            # round deferred to the flush — ack and return with only
-            # the request/ack legs on this UPDATE's clock
-            t += self.net.phase([Leg("update_ack", 8, f"s{ds}",
-                                     f"p{proxy.pid}",
-                                     self._is_failed(ds))])
-            proxy.ack(req.seq)
-            self.net.record(kind.upper(), t)
-            return True
-        # one submitted engine call serves every parity server (fused
-        # delta+apply over the gathered parity rows); resolution is safe
-        # before the crash check — engine calls carry no cluster state,
-        # and the per-row deltas extracted here feed the per-leg applies
-        fut = None
-        rows = None
-        if sealed and self.code.m > 0:
-            full = np.zeros(self.chunk_size, np.uint8)
-            full[seg_off: seg_off + len(seg)] = seg
-            old_par = np.stack([self._sv(p).parity_row(sl, cid.stripe_id)
-                                for p in sl.parity_servers])
-            fut = self.engine.submit_apply_delta(
-                old_par[None], np.array([cid.position]), full[None])
-            rows = fut.result()[0] ^ old_par
-        applied = 0
-        legs = []
-        for j, p in enumerate(sl.parity_servers):
-            if crash and applied >= self.crash_hook[2]:
-                self.crash_hook = None
-                raise PartialFailure(f"data server {ds} crashed after "
-                                     f"{applied} parity legs")
-            psrv = self._sv(p)
-            if sealed:
-                legs.append(Leg("delta", len(seg), f"s{ds}", f"s{p}",
-                                self._is_failed(p)))
-                psrv.apply_data_delta_row(sl, cid, rows[j], proxy.pid,
-                                          req.seq)
-            else:
-                nv = value if kind == "update" else b""
-                legs.append(Leg("replica_delta", len(key) + len(nv),
-                                f"s{ds}", f"s{p}", self._is_failed(p)))
-                psrv.apply_replica_delta(key, nv, kind == "delete",
-                                         proxy.pid, req.seq)
-            applied += 1
-        t += self._merge_coding(self._coding_s(fut), self.net.phase(legs),
-                                kind="delta")
-        t += self.net.phase([Leg(f"{kind}_ack", 8, f"s{ds}", f"p{proxy.pid}",
-                                 self._is_failed(ds))])
-        proxy.ack(req.seq)
-        # parity servers prune delta buffers using the ack watermark (§5.3)
-        for p in sl.parity_servers:
-            self._sv(p).prune_deltas(proxy.pid, proxy.ack_watermark)
-        self.net.record(kind.upper(), t)
-        return True
-
-    def _update_small(self, key: bytes, value: bytes, proxy_id: int) -> bool:
-        return self._mutate_small("update", key, value, proxy_id)
-
-    def _delete_small(self, key: bytes, proxy_id: int) -> bool:
-        return self._mutate_small("delete", key, None, proxy_id)
-
-    def _update_large(self, key: bytes, value: bytes, proxy_id: int) -> bool:
-        frags = split_fragments(key, value, self.chunk_size)
-        ok = True
-        for fkey, fval in frags:
-            ok &= self._update_small(fkey, fval, proxy_id)
-        return ok
-
-    def _delete_large(self, key: bytes, head: bytes, proxy_id: int) -> bool:
-        total = large_total(head)
-        nfrag = fragment_count(total, len(key), self.chunk_size)
-        for i in range(nfrag):
-            self._delete_small(key + struct.pack("<I", i), proxy_id)
-        return self._delete_small(key, proxy_id)
 
     # ------------------------------------------------------------------
     # degraded requests (§5.4) — all coordinated
@@ -1431,7 +1318,7 @@ class MemECCluster:
                     legs.append(Leg("set_replica", obj_bytes,
                                     f"p{proxy.pid}", f"s{p}"))
             t += self.net.phase(legs)
-            t += self._handle_seals(sl, ds, seal_events)
+            t += self._handle_seals([(sl, ds, ev) for ev in seal_events])
             proxy.buffer_mapping(ds, key, cid, iseq)
         self.net.record("SET_DEG", t)
         return True
@@ -1683,9 +1570,7 @@ class MemECCluster:
             self.net.record(f"{kind.upper()}_DEG", t)
             return False
         cid, sealed, off, xor = res
-        nz = np.nonzero(xor)[0]
-        seg_off = off + (int(nz[0]) if len(nz) else 0)
-        seg = xor[int(nz[0]): int(nz[-1]) + 1] if len(nz) else xor[:0]
+        seg_off, seg = self._changed_extent(off, xor)
         legs = []
         redirected: list[tuple[int, ReconChunk]] = []
         for j, p in enumerate(sl.parity_servers):
@@ -1778,10 +1663,7 @@ class MemECCluster:
             rc.buf[off + 4 + ksz: off + 4 + ksz + vsz] = 0
             rc.objects[key] = (off, ksz, vsz, True)
         rc.dirty = True
-        xor = old ^ rc.buf[off: off + ext]
-        nz = np.nonzero(xor)[0]
-        seg_off = off + (int(nz[0]) if len(nz) else 0)
-        seg = xor[int(nz[0]): int(nz[-1]) + 1] if len(nz) else xor[:0]
+        seg_off, seg = self._changed_extent(off, old ^ rc.buf[off: off + ext])
         legs = []
         redirected = []
         for j, p in enumerate(sl.parity_servers):
@@ -1912,14 +1794,10 @@ class MemECCluster:
                 if req.kind == "SET":
                     self._degraded_set(self.proxies[pid], req.stripe_list,
                                        req.data_server, req.key, req.value)
-                elif req.kind == "UPDATE":
-                    self._degraded_mutate("update", self.proxies[pid],
+                else:   # UPDATE / DELETE (a DELETE's value is None)
+                    self._degraded_mutate(req.kind.lower(), self.proxies[pid],
                                           req.stripe_list, req.data_server,
                                           req.key, req.value)
-                elif req.kind == "DELETE":
-                    self._degraded_mutate("delete", self.proxies[pid],
-                                          req.stripe_list, req.data_server,
-                                          req.key, None)
         return timings
 
     def _handoff_redirect_state(self, failing: int) -> float:
@@ -2046,10 +1924,10 @@ class MemECCluster:
                 self._stats["migrated_objects"] += 1
                 ref = restored.lookup(okey)
                 if ref is not None and ref.value_size == len(val):
-                    self._update_small(okey, val, 0)
+                    self._mutate_small("update", okey, val, 0)
                 else:
                     if ref is not None:
-                        self._delete_small(okey, 0)
+                        self._mutate_small("delete", okey, None, 0)
                     self._set_small(okey, val, 0)
             for okey in list(rs.temp_deletes):
                 sl2, ds2 = self.mapper.data_server_for(okey)
@@ -2057,7 +1935,7 @@ class MemECCluster:
                     continue
                 rs.temp_deletes.discard(okey)
                 if restored.lookup(okey) is not None:
-                    self._delete_small(okey, 0)
+                    self._mutate_small("delete", okey, None, 0)
             # 3. shadow replicas destined to sid (it was a parity server).
             # One shadow entry serves every failed parity of the list that
             # redirected here, so migrate a COPY and only drop the entry

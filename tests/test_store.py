@@ -221,6 +221,37 @@ class TestConsistencyResolution:
         assert bad == 0
 
 
+    def test_partial_delete_revert_and_replay(self):
+        """§5.3 for DELETE: a sealed object's DELETE interrupted after one
+        of its two parity legs is reverted and replayed as a degraded
+        DELETE; the tombstone survives the restore."""
+        cl = make_cluster()
+        kv, _ = load(cl, 4000)
+        target = None
+        for k in kv:
+            sl, ds = cl.mapper.data_server_for(k)
+            ref = cl.servers[ds].lookup(k)
+            if ref is not None and cl.servers[ds].sealed[ref.chunk_local_idx]:
+                target = (k, ds)
+                break
+        assert target is not None
+        key, ds = target
+        cl.crash_hook = ("delete", key, 1)
+        with pytest.raises(PartialFailure):
+            cl.delete(key)
+        assert cl.crash_hook is None
+        assert any(p.pending for p in cl.proxies)
+        cl.fail_server(ds)
+        assert cl.stats["reverted_deltas"] >= 1
+        assert cl.get(key) is None
+        cl.restore_server(ds)
+        assert cl.get(key) is None
+        del kv[key]
+        assert check_all(cl, kv) == 0
+        _, bad = parity_invariant(cl)
+        assert bad == 0
+
+
 class TestRedundancyAccounting:
     def test_measured_redundancy_tracks_formula(self):
         """Loaded-store byte accounting approaches the §3.3 analysis."""
@@ -264,6 +295,29 @@ class TestStateTransitions:
         t_busy = cl.fail_server(ds)["T_N_to_D"]
         assert t_idle < 1.0 and t_busy < 1.0
         assert t_busy >= t_idle * 0.5  # busy path includes revert work
+
+
+    def test_transition_timings_shape_delete(self):
+        """As above, with the hanging request a DELETE of an unsealed
+        object: its replica deltas are reverted, and the DELETE replays."""
+        cl = make_cluster()
+        kv, _ = load(cl, 1500)
+        t_idle = cl.fail_server(3)["T_N_to_D"]
+        cl.restore_server(3)
+
+        def unsealed(k):
+            srv = cl.servers[cl.mapper.data_server_for(k)[1]]
+            return not srv.sealed[srv.lookup(k).chunk_local_idx]
+        key = next(k for k in kv if unsealed(k))
+        cl.crash_hook = ("delete", key, 1)
+        with pytest.raises(PartialFailure):
+            cl.delete(key)
+        sl, ds = cl.mapper.data_server_for(key)
+        t_busy = cl.fail_server(ds)["T_N_to_D"]
+        assert t_idle < 1.0 and t_busy < 1.0
+        assert t_busy >= t_idle * 0.5  # busy path includes revert work
+        assert cl.stats["reverted_deltas"] >= 1
+        assert cl.get(key) is None
 
 
 class TestReSetInstanceHardening:
