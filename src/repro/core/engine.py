@@ -37,8 +37,9 @@ cluster can issue coding work while the same shard's netsim legs are
 modeled in flight (``async_engine=True`` / ``$MEMEC_ASYNC``).  The numpy
 backend resolves lazily (the work runs at ``result()``); the jax and
 pallas backends *dispatch* on-device at submit time — XLA's async
-dispatch does the real overlapping — and call ``jax.block_until_ready``
-only at resolution.  Every future carries a deterministic ``work_bytes``
+dispatch does the real overlapping — queue each result's copy to the
+host behind it at once, and call ``jax.block_until_ready`` only at
+resolution.  Every future carries a deterministic ``work_bytes``
 figure (GF(2^8) multiply-accumulate bytes) that ``CostModel.coding_s``
 turns into modeled time; results are byte-identical to the blocking
 calls by construction.
@@ -237,6 +238,10 @@ class CodingEngine:
         # the same counts per call)
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        # device results whose copy to the host started at dispatch, and
+        # device results resolved: equal once every future has resolved
+        self.copies_at_dispatch = 0
+        self.results_fetched = 0
         # per-op dispatch provenance: every device hook records which
         # path actually ran it ("pallas-compiled" / "xla-compiled" /
         # "interpret" / "jnp-fallback") — a silent jnp fallback used to
@@ -303,14 +308,17 @@ class CodingEngine:
         }
 
     def stats(self) -> dict:
-        """Run counters: device dispatches, bytes each way and
-        plan-cache occupancy."""
+        """Run counters: device dispatches, bytes each way, device
+        results fetched (and how many had their copy started at
+        dispatch) and plan-cache occupancy."""
         return {
             "path": self.describe()["path"],
             "op_paths": dict(self.op_paths),
             "device_dispatches": self.device_dispatches,
             "h2d_bytes": self.h2d_bytes,
             "d2h_bytes": self.d2h_bytes,
+            "copies_at_dispatch": self.copies_at_dispatch,
+            "results_fetched": self.results_fetched,
             "inv_cache": len(self._inv_cache),
             "fused_cache": len(self._fused_cache),
             "modeled_busy_s": self.modeled_busy_s,
@@ -612,15 +620,28 @@ class JaxEngine(CodingEngine):
                  interpret_forced=dispatch.interpret_forced())
         return d
 
+    def _device_future(self, dev, shape, work_bytes: int = 0,
+                       kind: str = "") -> EngineFuture:
+        """The future of a dispatched device array, the one way a result
+        comes home: its copy to the host is queued behind the computation
+        now, so ``result()`` finds it under way or landed."""
+        dev.copy_to_host_async()
+        self.copies_at_dispatch += 1
+        return EngineFuture(lambda: self._resolve_dev(dev, shape),
+                            work_bytes, kind)
+
     def _resolve_dev(self, dev, shape):
         """Blocking resolution of a dispatched device array (the only
-        place the async path waits on the device)."""
+        place the async path waits on the device): wait for the
+        computation, then for what is left of the copy started at
+        dispatch."""
         jax, _ = _jax()
         with span(spans.ENGINE_WAIT):
             jax.block_until_ready(dev)
         with span(spans.ENGINE_FETCH, bytes=dev.nbytes):
             out = np.asarray(dev).reshape(shape)
         self.d2h_bytes += out.nbytes
+        self.results_fetched += 1
         return out
 
     def submit_encode(self, data):
@@ -632,8 +653,7 @@ class JaxEngine(CodingEngine):
             return EngineFuture.wrap(np.zeros((B, m, C), np.uint8), wb,
                                      "encode")
         dev = self._matmul_dev(self.rep.encode, self._blocks(data))
-        return EngineFuture(lambda: self._resolve_dev(dev, (B, m, C)),
-                            wb, "encode")
+        return self._device_future(dev, (B, m, C), wb, "encode")
 
     def submit_delta(self, data_indices, xors):
         xors = np.asarray(xors, dtype=np.uint8)
@@ -646,8 +666,7 @@ class JaxEngine(CodingEngine):
         with span(spans.ENGINE_PREPARE):
             Ms = self._delta_matrices(data_indices)
         dev = self._matmul_per_item_dev(Ms, xors.reshape(B, r, C // r))
-        return EngineFuture(lambda: self._resolve_dev(dev, (B, m, C)),
-                            wb, "delta")
+        return self._device_future(dev, (B, m, C), wb, "delta")
 
     def submit_fold_rows(self, data_indices, xors, row_indices, parity_rows):
         """Fused: per item, the (r, r) sub-system for ONE parity row is
@@ -670,8 +689,7 @@ class JaxEngine(CodingEngine):
             Ms = np.ascontiguousarray(E4[rows, :, idx, :])    # (B, r, r)
         dev = self._matmul_per_item_dev(Ms, xors.reshape(B, r, C // r),
                                         parity_rows.reshape(B, r, C // r))
-        return EngineFuture(lambda: self._resolve_dev(dev, (B, C)),
-                            wb, "fold")
+        return self._device_future(dev, (B, C), wb, "fold")
 
     def submit_apply_delta(self, parity, data_indices, xors):
         """Fused delta + parity apply in one per-item device call (the
@@ -688,8 +706,7 @@ class JaxEngine(CodingEngine):
             Ms = self._delta_matrices(data_indices)
         dev = self._matmul_per_item_dev(Ms, xors.reshape(B, r, C // r),
                                         parity.reshape(B, m * r, C // r))
-        return EngineFuture(lambda: self._resolve_dev(dev, (B, m, C)),
-                            wb, "apply_delta")
+        return self._device_future(dev, (B, m, C), wb, "apply_delta")
 
     def apply_delta_batch(self, parity, data_indices, xors):
         return self.submit_apply_delta(parity, data_indices, xors).result()
@@ -722,8 +739,7 @@ class JaxEngine(CodingEngine):
         dev = self._matmul_per_item_dev(
             Ms, collapsed.reshape(B, r, C // r),
             parity.reshape(B, m * r, C // r))
-        return EngineFuture(lambda: self._resolve_dev(dev, (B, m, C)),
-                            wb, "delta_collapse")
+        return self._device_future(dev, (B, m, C), wb, "delta_collapse")
 
     def _delta_matrices(self, data_indices) -> np.ndarray:
         """(B, m*r, r) per item: the encode matrix's columns of its
@@ -760,38 +776,42 @@ class JaxEngine(CodingEngine):
         with span(spans.ENGINE_PREPARE):
             plan = self.plan_decode([a.keys() for a in available], wanted,
                                     chunk_size)
-        devs = self._execute_decode_dev(plan, available)
-        return EngineFuture(lambda: self._scatter_decode(plan, devs),
+        futs = self._execute_decode_dev(plan, available)
+        return EngineFuture(lambda: self._scatter_decode(plan, futs),
                             wb, "decode")
 
-    def _execute_decode_dev(self, plan: DecodePlan, available) -> list:
-        """Execute stage: ONE batched device matmul per pattern group.
+    def _execute_decode_dev(self, plan: DecodePlan,
+                            available) -> list[EngineFuture]:
+        """Execute stage: ONE batched device matmul per pattern group,
+        each group's output (Bg, k + n_par, C) behind its own future.
 
         The group's inverse and its re-encoded-parity rows are fused into
         a single host-composed matrix (``_fused_decode_matrix``), so the
         old matmul -> parity-re-encode chain collapses to one kernel —
         byte-checked against the two-call composition in
         ``tests/test_dispatch_tune.py``."""
-        devs = []
+        k, C = self.code.k, plan.chunk_size
+        futs = []
         for g in plan.groups:
             with span(spans.ENGINE_PREPARE):
                 stacked = np.stack(
                     [np.stack([np.asarray(available[i][p], np.uint8)
                                for p in g.use]) for i in g.idxs])  # (Bg, k, C)
                 M = self._fused_decode_matrix(g)
-            devs.append(self._matmul_dev(M, self._blocks(stacked)))
-        return devs
+            dev = self._matmul_dev(M, self._blocks(stacked))
+            futs.append(self._device_future(
+                dev, (len(g.idxs), k + len(g.need_par), C)))
+        return futs
 
-    def _scatter_decode(self, plan: DecodePlan, devs) -> list[dict]:
-        """Resolution: block on the dispatched groups and scatter each
+    def _scatter_decode(self, plan: DecodePlan, futs) -> list[dict]:
+        """Resolution: resolve the groups' futures and scatter each
         item's wanted positions back into per-stripe dicts.  The fused
         matmul output is (Bg, k + n_par, C): data rows then the
         re-encoded parity rows."""
-        k, C = self.code.k, plan.chunk_size
+        k = self.code.k
         results: list[dict | None] = [None] * plan.n_items
-        for g, dev in zip(plan.groups, devs):
-            Bg, npar = len(g.idxs), len(g.need_par)
-            out = self._resolve_dev(dev, (Bg, k + npar, C))
+        for g, fut in zip(plan.groups, futs):
+            out = fut.result()
             for bi, i in enumerate(g.idxs):
                 results[i] = {w: (out[bi, w] if w < k
                                   else out[bi, k + g.need_par.index(w)])
@@ -869,8 +889,7 @@ class PallasEngine(JaxEngine):
         # parity=None: delta-only kernel — no dead parity streams
         self._dispatch("delta", dispatch.decide().path, gammas, xors)
         dev = delta_apply_batched(None, gammas, xors)
-        return EngineFuture(
-            lambda: self._resolve_dev(dev, (B, self.code.m, C)), wb, "delta")
+        return self._device_future(dev, (B, self.code.m, C), wb, "delta")
 
     def submit_apply_delta(self, parity, data_indices, xors):
         if self.rep.r != 1 or self.code.m == 0:
@@ -888,8 +907,7 @@ class PallasEngine(JaxEngine):
             gammas = self._gammas(data_indices)
         self._dispatch("delta", dispatch.decide().path, parity, gammas, xors)
         dev = delta_apply_batched(parity, gammas, xors)
-        return EngineFuture(
-            lambda: self._resolve_dev(dev, parity.shape), wb, "apply_delta")
+        return self._device_future(dev, parity.shape, wb, "apply_delta")
 
 
 # ---------------------------------------------------------------------------

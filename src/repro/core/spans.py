@@ -38,7 +38,8 @@ STORE_DELTA_LOG = "memec.store.delta_log"
 NETSIM = "memec.netsim"
 
 # coding engine (core/engine.py): gamma / matrix / decode-plan
-# preparation on the host, the wait for the device, the copy back
+# preparation on the host, the wait for the computation, the wait for
+# the rest of the copy back (started at dispatch)
 ENGINE_PREPARE = "memec.engine.prepare"
 ENGINE_WAIT = "memec.engine.wait"
 ENGINE_FETCH = "memec.engine.fetch"

@@ -213,3 +213,75 @@ def test_make_engine_selection(monkeypatch):
     with pytest.raises(ValueError):
         make_engine("isal", code)
     assert set(ENGINES) == {"numpy", "jax", "pallas"}
+
+
+# ---------------------------------------------------------------------------
+# copy to the host at dispatch: every device result a submit path hands
+# back has its copy started at submit, and is fetched once at result()
+# ---------------------------------------------------------------------------
+SUBMIT_C = 64
+
+
+def _submit_case(path, rng):
+    """(scheme, call) for one submit path; ``call(eng)`` submits it."""
+    B, C = 3, SUBMIT_C
+    idx = [0, 3, 7]
+    xors = rng.integers(0, 256, (B, C), dtype=np.uint8)
+    parity = rng.integers(0, 256, (B, 2, C), dtype=np.uint8)
+    if path == "encode":
+        data = rng.integers(0, 256, (B, 8, C), dtype=np.uint8)
+        return "rs", lambda eng: eng.submit_encode(data)
+    if path in ("rs_delta", "rdp_delta"):
+        return path[:-6], lambda eng: eng.submit_delta(idx, xors)
+    if path in ("rs_apply_delta", "rdp_apply_delta"):
+        return path[:-12], lambda eng: eng.submit_apply_delta(parity, idx,
+                                                              xors)
+    if path == "fold_rows":
+        rows = rng.integers(0, 256, (B, C), dtype=np.uint8)
+        return "rdp", lambda eng: eng.submit_fold_rows(idx, xors, [0, 1, 1],
+                                                       rows)
+    if path == "delta_collapse":
+        versions = [rng.integers(0, 256, (v, C), dtype=np.uint8)
+                    for v in (1, 3, 2)]
+        return "rs", lambda eng: eng.submit_delta_collapse(parity, idx,
+                                                           versions)
+    assert path == "decode"
+    code = make_code("rs", 10, 8)
+    data = rng.integers(0, 256, (4, 8, C), dtype=np.uint8)
+    stripes = np.concatenate(
+        [data, np.stack([code.encode(d) for d in data])], axis=1)
+    # two erasure patterns: {2} for stripes 0-1, {5, 9} for stripes 2-3
+    erased = [[2], [2], [5, 9], [5, 9]]
+    avail = [{p: s[p] for p in range(10) if p not in e}
+             for s, e in zip(stripes, erased)]
+    return "rs", lambda eng: eng.submit_decode(avail, erased, C)
+
+
+@pytest.mark.parametrize("backend", ("jax", "pallas"))
+@pytest.mark.parametrize("path", ("encode", "rs_delta", "rs_apply_delta",
+                                  "rdp_delta", "rdp_apply_delta",
+                                  "fold_rows", "delta_collapse", "decode"))
+def test_submit_starts_the_copy_home_at_dispatch(path, backend):
+    scheme, call = _submit_case(path, np.random.default_rng(5))
+    code = make_code(scheme, 10, 8)
+    ref_eng = NumpyEngine(code)
+    want = call(ref_eng).result()
+    assert (ref_eng.copies_at_dispatch, ref_eng.results_fetched) == (0, 0)
+
+    eng = make_engine(backend, code)
+    fut = call(eng)
+    groups = 2 if path == "decode" else 1
+    assert eng.copies_at_dispatch == groups       # at submit
+    assert eng.results_fetched == 0
+    got = fut.result()
+    assert eng.results_fetched == eng.copies_at_dispatch == groups
+    assert eng.stats()["copies_at_dispatch"] == groups
+    assert eng.stats()["results_fetched"] == groups
+    if path == "decode":
+        assert [sorted(g) for g in got] == [sorted(w) for w in want]
+        for g, w in zip(got, want):
+            for pos in w:
+                assert g[pos].tobytes() == w[pos].tobytes(), pos
+    else:
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()

@@ -99,6 +99,7 @@ def test_update_path_spans_nest_on_one_host_line(tmp_path):
     cl.multi_update(items)                  # compile outside the trace
     items = [(k, b"x" * 8) for k, _ in items]
     h0, d0 = cl.engine.h2d_bytes, cl.engine.d2h_bytes
+    c0, r0 = cl.engine.copies_at_dispatch, cl.engine.results_fetched
     jax.profiler.start_trace(str(tmp_path))
     with TraceAnnotation("bench.multi_update", ops=len(items)):
         assert all(cl.multi_update(items))
@@ -121,6 +122,14 @@ def test_update_path_spans_nest_on_one_host_line(tmp_path):
         assert events.get(name), f"{name} not on the store's host line"
         for e in events[name]:
             assert store.start_ns <= e.start_ns <= e.end_ns <= store.end_ns
+    # one wait and one fetch per resolved result, each result's copy
+    # started at dispatch, and the fetch spans' bytes are d2h_bytes
+    fetched = cl.engine.results_fetched - r0
+    assert fetched == cl.engine.copies_at_dispatch - c0 > 0
+    assert len(events[spans.ENGINE_WAIT]) == fetched
+    assert len(events[spans.ENGINE_FETCH]) == fetched
+    assert sum(dict(e.stats)["bytes"] for e in events[spans.ENGINE_FETCH]) \
+        == cl.engine.d2h_bytes - d0
     (log,) = events[spans.STORE_DELTA_LOG]
     for name in (spans.ENGINE_WAIT, spans.ENGINE_FETCH):
         (e,) = events[name]
