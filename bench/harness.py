@@ -6,7 +6,8 @@ objects through ``multi_set``, warms up every shape the window will use,
 measures for ``seconds`` on the open-loop driver, then compares what the
 window produced with the plain reference (``bench/reference.py``) and
 returns the result line.  Metrics are read by ``bench/metrics/<name>.py``
-from the ``Run`` record.
+from the ``Run`` record, which also carries the window's change in every
+counter of the store and of its coding engine (``Run.counters``).
 """
 from __future__ import annotations
 
@@ -28,6 +29,10 @@ PALLAS = "pallas-compiled"
 TRACE_AT = 0.4        # the traced sub-window opens at this share of the window
 TRACE_S = 4.0         # and lasts this long
 TRACE_DIR = spec.BENCH / ".traces"
+# coding ops that only some kinds of request run: the RS parity delta of
+# a sealed object's UPDATE (or upsert); seals fold through
+# ``delta_per_item`` and decodes through ``matmul`` whatever the mix
+OP_KINDS = {"delta": ("update", "set")}
 
 
 class BenchFailure(Exception):
@@ -143,6 +148,9 @@ class Run:
     recover_s: list | None
     trace_bounds: tuple | None  # driver-clock span the profiler covered
     trace: object | None        # bench.trace.Reduction of the traced run
+    # change over the traffic window of each numeric entry of the store's
+    # ``stats`` and ``wall_stats`` and of its engine's ``stats()``
+    counters: dict = dataclasses.field(default_factory=dict)
 
     @property
     def latency_s(self) -> np.ndarray:
@@ -163,6 +171,34 @@ def cluster_kwargs(cfg: dict) -> dict:
                 placement=cfg["placement"], async_engine=cfg["async_engine"],
                 redundant_reads=cfg["redundant_reads"],
                 hot_key_threshold=cfg["hot_key_threshold"])
+
+
+def counters(cluster) -> dict:
+    """Every numeric counter of the store (``stats``, ``wall_stats``) and
+    of its coding engine (``stats()``), by name; a name in two of them
+    keeps the store's ``stats`` value."""
+    out = {}
+    for src in (cluster.engine.stats(), cluster.wall_stats, cluster.stats):
+        out.update((k, v) for k, v in src.items()
+                   if isinstance(v, (int, float)) and not isinstance(v, bool))
+    return out
+
+
+def sealed_data_chunks(cluster) -> int:
+    """Sealed data chunks held by all servers (a seal adds one)."""
+    return sum(sealed and cid.position < cluster.k
+               for s in cluster.servers
+               for cid, sealed in zip(s.chunk_ids, s.sealed)
+               if cid is not None)
+
+
+def required_ops(cfg: dict, traffic: dict) -> set:
+    """The coding ops a run must show: the configuration's and the
+    traffic's, less those that only kinds absent from the mix run."""
+    mix = {k for k, share in traffic["mix"].items() if share > 0}
+    return {op for op in (set(cfg["required_ops"])
+                          | set(traffic.get("required_ops", ())))
+            if mix.intersection(OP_KINDS.get(op, mix))}
 
 
 def load(client: CheckedClient, cfg: dict, seed: int) -> None:
@@ -284,8 +320,7 @@ def prepare(workload: str, seed: int, *, bench: dict | None = None,
     cluster = make_cluster(**cluster_kwargs(cfg))
     client = CheckedClient(cluster)
     load(client, cfg, seed)
-    ctx = Ctx(cluster, client, cfg, traffic,
-              set(cfg["required_ops"]) | set(traffic.get("required_ops", ())))
+    ctx = Ctx(cluster, client, cfg, traffic, required_ops(cfg, traffic))
     if hook is not None:
         hook.setup(ctx)
     return Cell(cfg, traffic, hook, ctx, counter, devices, expected_path)
@@ -327,7 +362,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         raise BenchFailure(f"--seconds {seconds} leaves no traffic window")
     warm = make_stream(cfg, traffic, rate, traffic["warmup_s"],
                        rng_for(seed, 1))
-    stream = make_stream(cfg, traffic, rate, traffic_s, rng_for(seed, 2))
+    # the window's inserts take the ids after the warm-up's
+    stream = make_stream(cfg, traffic, rate, traffic_s, rng_for(seed, 2),
+                         first_insert_id=cfg["objects"] + warm.inserts)
     warm_up(cell, [warm, stream])
     # every window starts from a fresh full collection, not from wherever
     # the load left the collector's generation counts
@@ -342,12 +379,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if hook is not None:
         hook.open_window(ctx)
     tracer = Tracer(traffic_s, workload) if trace else None
+    counters0, sealed0 = counters(cluster), sealed_data_chunks(cluster)
     dispatches0 = engine.device_dispatches
     t_traffic = time.perf_counter()
     with GcPauses() as gc_pauses:
         rec = drive(client, stream, max_window=maxw,
                     num_proxies=cfg["num_proxies"], engine=engine,
                     t0=t_traffic, tick=tracer.tick if tracer else None)
+    counters1, sealed1 = counters(cluster), sealed_data_chunks(cluster)
     if tracer is not None:
         tracer.stop(time.perf_counter() - t_traffic)
     window_s = time.perf_counter() - t_window
@@ -392,7 +431,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
               dispatches_at_open=dispatches0, compiles_in_window=compiles,
               recover_s=ctx.recover_s,
               trace_bounds=tracer.bounds if tracer else None,
-              trace=reduction)
+              trace=reduction,
+              counters={k: v - counters0.get(k, 0)
+                        for k, v in counters1.items()})
     metrics = {}
     for m in spec.metrics_for(bench, workload, trace):
         v = spec.reader(m["name"])(run)
@@ -410,6 +451,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = reduction.breakdown()
     log(json.dumps({"window_s": window_s, "windows": len(rec.windows),
                     "stripes_checked": stripes_checked,
+                    "sealed_data_chunks": [sealed0, sealed1],
+                    "counters": {k: v for k, v in run.counters.items() if v},
                     "chunks_checked": chunks_checked,
                     "reads_checked": client.reads,
                     "backlog_at_close": int(np.sum(
